@@ -14,8 +14,7 @@ serving-layer claims as measurements:
   request while the server kept serving;
 * p50/p99 latency and throughput for the concurrent phase.
 
-Run:  BLAZE_PALLAS_INTERPRET=1 PYTHONPATH=src:. \\
-          python -m benchmarks.bench6_serve
+Run:  PYTHONPATH=src:. python -m benchmarks.bench6_serve
 Writes ``results/BENCH_6.json``.  ``BENCH_SCALE=smoke`` shrinks datasets
 for CI; ``BENCH_SCALE=big`` grows them 4x.
 """

@@ -19,6 +19,8 @@ import os
 import subprocess
 import sys
 
+from repro.launch.simulate import simulated_env
+
 _CHILD = """
 import json, numpy as np, jax, jax.numpy as jnp
 from repro.core import data_mesh, distribute, make_dist_hashmap, map_reduce
@@ -48,8 +50,7 @@ print(json.dumps(out))
 
 
 def run_at(n_devices: int) -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    env = simulated_env(n_devices)
     env.setdefault("PYTHONPATH", "src")
     p = subprocess.run(
         [sys.executable, "-c", _CHILD], capture_output=True, text=True, env=env,

@@ -7,7 +7,7 @@ queries coalesce into micro-batched dispatches.  The closing /stats
 snapshot shows the ledger: compiles vs cache hits, batched dispatches,
 p50/p99 latency.
 
-Run:  BLAZE_PALLAS_INTERPRET=1 PYTHONPATH=src python examples/serve_queries.py
+Run:  PYTHONPATH=src python examples/serve_queries.py
 """
 import threading
 

@@ -1,26 +1,18 @@
-"""JAX version-compatibility shims.
+"""The JAX surface the package is written against, named in one place.
 
-The codebase is written against the modern JAX surface (``jax.shard_map``,
-``jax.make_mesh(..., axis_types=...)``, ``jax.set_mesh``); this module makes
-those spellings work on every JAX back to 0.4.x so the package imports and
-runs on whatever the container ships.  All internal code imports these names
-from here, never from ``jax`` directly:
+The codebase targets JAX 0.9 (``jax.shard_map``, ``jax.make_mesh(...,
+axis_types=...)``, ``jax.set_mesh``).  Internal code imports these names from
+here rather than from ``jax`` directly, so a future API move is one edit:
 
-* ``shard_map``   — ``jax.shard_map`` when present, else
-                    ``jax.experimental.shard_map.shard_map``; the replication
-                    check flag (``check_rep`` pre-0.5, ``check_vma`` after) is
-                    normalised so callers may pass either.
-* ``make_mesh``   — drops ``axis_types`` when the installed ``jax.make_mesh``
-                    predates it (Auto is the old default behaviour anyway).
-* ``set_mesh``    — ``jax.set_mesh`` when present, else the 0.4.x ambient
-                    mesh context (``Mesh`` is itself a context manager).
-* ``AxisType``    — ``jax.sharding.AxisType`` or a placeholder enum.
+* ``shard_map``, ``make_mesh``, ``set_mesh``, ``AxisType``,
+  ``get_abstract_mesh``, ``process_count``, ``process_index`` — aliases of
+  the JAX functions of the same name;
+* ``distributed_initialize`` — ``jax.distributed.initialize`` that is a
+  no-op for a single-process launch.
 """
 from __future__ import annotations
 
-import enum
-import inspect
-from typing import Any, Callable, Sequence
+from typing import Any
 
 import jax
 
@@ -35,8 +27,13 @@ __all__ = [
     "shard_map",
 ]
 
-
-# -- multi-process bring-up --------------------------------------------------
+AxisType = jax.sharding.AxisType
+get_abstract_mesh = jax.sharding.get_abstract_mesh
+make_mesh = jax.make_mesh
+process_count = jax.process_count
+process_index = jax.process_index
+set_mesh = jax.set_mesh
+shard_map = jax.shard_map
 
 
 def distributed_initialize(
@@ -45,7 +42,7 @@ def distributed_initialize(
     process_id: int | None = None,
     **kwargs: Any,
 ) -> bool:
-    """``jax.distributed.initialize`` gated for single-process and old JAX.
+    """``jax.distributed.initialize`` gated for single-process launches.
 
     Returns True iff a multi-process runtime actually came up.  A
     single-process launch (no coordinator, ``num_processes`` absent or 1) is
@@ -53,132 +50,12 @@ def distributed_initialize(
     is what lets the simulated-topology harness and a real cluster share one
     entry point (``repro.launch.mesh.init_distributed``).
     """
-    single = coordinator_address is None and num_processes in (None, 1)
-    dist = getattr(jax, "distributed", None)
-    if single or dist is None or not hasattr(dist, "initialize"):
+    if coordinator_address is None and num_processes in (None, 1):
         return False
-    dist.initialize(
+    jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,
         process_id=process_id,
         **kwargs,
     )
     return True
-
-
-def process_count() -> int:
-    return jax.process_count() if hasattr(jax, "process_count") else 1
-
-
-def process_index() -> int:
-    return jax.process_index() if hasattr(jax, "process_index") else 0
-
-
-# -- shard_map ---------------------------------------------------------------
-
-if hasattr(jax, "shard_map"):
-    _shard_map_impl = jax.shard_map
-else:  # JAX <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_SHARD_MAP_PARAMS = frozenset(inspect.signature(_shard_map_impl).parameters)
-
-
-def shard_map(
-    f: Callable,
-    *,
-    mesh,
-    in_specs,
-    out_specs,
-    check_vma: bool | None = None,
-    check_rep: bool | None = None,
-    **kwargs: Any,
-):
-    """``jax.shard_map`` on every supported JAX version.
-
-    Accepts both spellings of the replication-check flag and forwards
-    whichever one the installed JAX understands.
-    """
-    check = check_vma if check_vma is not None else check_rep
-    if check is not None:
-        for name in ("check_vma", "check_rep"):
-            if name in _SHARD_MAP_PARAMS:
-                kwargs[name] = check
-                break
-    return _shard_map_impl(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-    )
-
-
-# -- AxisType ----------------------------------------------------------------
-
-if hasattr(jax.sharding, "AxisType"):
-    AxisType = jax.sharding.AxisType
-else:
-
-    class AxisType(enum.Enum):  # type: ignore[no-redef]
-        """Placeholder for ``jax.sharding.AxisType`` on old JAX, where every
-        mesh axis implicitly behaves like ``Auto``."""
-
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-
-# -- make_mesh ---------------------------------------------------------------
-
-_MAKE_MESH_PARAMS = (
-    frozenset(inspect.signature(jax.make_mesh).parameters)
-    if hasattr(jax, "make_mesh")
-    else frozenset()
-)
-
-
-def make_mesh(
-    axis_shapes: Sequence[int],
-    axis_names: Sequence[str],
-    *,
-    axis_types=None,
-    devices=None,
-):
-    if hasattr(jax, "make_mesh"):
-        kwargs: dict[str, Any] = {}
-        if devices is not None:
-            kwargs["devices"] = devices
-        if axis_types is not None and "axis_types" in _MAKE_MESH_PARAMS:
-            kwargs["axis_types"] = axis_types
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
-    import numpy as np
-
-    devs = list(devices) if devices is not None else jax.devices()
-    n = int(np.prod(axis_shapes))
-    return jax.sharding.Mesh(
-        np.asarray(devs[:n]).reshape(tuple(axis_shapes)), tuple(axis_names)
-    )
-
-
-# -- get_abstract_mesh -------------------------------------------------------
-
-if hasattr(jax.sharding, "get_abstract_mesh"):
-    get_abstract_mesh = jax.sharding.get_abstract_mesh
-else:
-
-    def get_abstract_mesh():  # type: ignore[misc]
-        """Old-JAX fallback: the ambient physical mesh installed by
-        ``with mesh:`` exposes the same ``.axis_names`` / ``.shape`` surface
-        (empty mesh ⇒ ``axis_names == ()``, matching "no mesh active")."""
-        from jax._src import mesh as mesh_lib
-
-        return mesh_lib.thread_resources.env.physical_mesh
-
-
-# -- set_mesh ----------------------------------------------------------------
-
-if hasattr(jax, "set_mesh"):
-    set_mesh = jax.set_mesh
-else:
-
-    def set_mesh(mesh):  # type: ignore[misc]
-        """Old-JAX fallback: ``Mesh`` is a context manager that installs the
-        ambient mesh, which is what ``jax.set_mesh`` does on new JAX."""
-        return mesh

@@ -302,17 +302,23 @@ def kmeans_reference(
     points: np.ndarray, init_centers: np.ndarray, tol: float = 1e-4,
     max_iters: int = 50,
 ) -> tuple[np.ndarray, int]:
-    """numpy oracle (same init, same convergence rule)."""
+    """numpy oracle (same init, same convergence rule).  Points are taken a
+    chunk at a time, so the oracle's memory stays flat in ``len(points)``."""
     centers = init_centers.astype(np.float64).copy()
-    k = centers.shape[0]
+    k, dim = centers.shape
+    chunk = 1 << 20
     for it in range(1, max_iters + 1):
-        d2 = ((points[:, None, :] - centers[None, :, :]) ** 2).sum(-1)
-        assign = d2.argmin(1)
-        new = np.stack(
-            [
-                points[assign == j].mean(0) if (assign == j).any() else centers[j]
-                for j in range(k)
-            ]
+        sums = np.zeros((k, dim))
+        counts = np.zeros(k, np.int64)
+        for lo in range(0, len(points), chunk):
+            x = points[lo:lo + chunk].astype(np.float64)
+            d2 = ((x[:, None, :] - centers[None, :, :]) ** 2).sum(-1)
+            assign = d2.argmin(1)
+            counts += np.bincount(assign, minlength=k)
+            for j in range(dim):
+                sums[:, j] += np.bincount(assign, x[:, j], minlength=k)
+        new = np.where(
+            counts[:, None] > 0, sums / np.maximum(counts, 1)[:, None], centers
         )
         move = ((new - centers) ** 2).sum(1).max()
         centers = new

@@ -297,8 +297,10 @@ def pagerank_reference(
     scores = np.full(n_pages, 1.0 / n_pages, np.float64)
     for _ in range(max_iters):
         sink_total = scores[deg == 0].sum()
-        incoming = np.zeros(n_pages)
-        np.add.at(incoming, edges[:, 1], scores[edges[:, 0]] / np.maximum(deg[edges[:, 0]], 1))
+        incoming = np.bincount(
+            edges[:, 1], scores[edges[:, 0]] / np.maximum(deg[edges[:, 0]], 1),
+            minlength=n_pages,
+        )
         new = (1 - damping) / n_pages + damping * (incoming + sink_total / n_pages)
         if np.abs(new - scores).max() < tol:
             scores = new

@@ -58,18 +58,23 @@ __all__ = [
     "choose_probe_depth",
     "choose_table_cap",
     "dense_tuning_candidates",
+    "hash_block_n",
     "hash_table_candidates",
     "hash_tuning_candidates",
     "next_capacity",
     "node_cost",
     "pick_engine",
     "segment_block_candidates",
+    "segment_working_set",
     "use_matmul",
 ]
 
-# Default VMEM budget for both kernel autotuners (bytes).  Real cores have
-# ~16 MB; leave room for the accumulator tile and double-buffered inputs.
-VMEM_BUDGET = 4 * 1024 * 1024
+# Default VMEM budget for both kernel autotuners (bytes).  Mosaic's scoped
+# VMEM limit on a v5e core is 16 MiB (the limit its compiler reports when a
+# kernel overflows); the working-set models below count lane/sublane padding
+# as Mosaic lays tiles out, and the budget keeps a quarter of the limit free
+# for spills and semaphores.
+VMEM_BUDGET = 12 * 1024 * 1024
 
 # The fallback cost model's calibration anchor.  The kernel pays ~2
 # accumulator-row units per key, eager pays ~1 unit per key plus this fixed
@@ -105,6 +110,44 @@ def use_matmul(reducer: str, acc) -> bool:
 # ---------------------------------------------------------------------------
 
 
+# Pair blocks are whole 128-lane tiles (Mosaic's vector width); the grids
+# below walk powers of two from one tile up to ``MAX_BLOCK``.
+LANES = 128
+MAX_BLOCK = 2048
+
+
+def _pad(x: int, m: int) -> int:
+    return -(-max(x, 1) // m) * m
+
+
+def segment_working_set(
+    bn: int, num_segments: int, v: int, reducer: str = "sum",
+    dtype=jnp.float32,
+) -> int:
+    """VMEM bytes one grid step of the dense kernel holds at block ``bn``.
+
+    Counted as Mosaic lays them out — sublanes padded to 8, lanes to 128:
+    the double-buffered ``[1, bn]`` id and ``[V, bn]`` value blocks, the
+    double-buffered ``[K, V]`` accumulator, and ~3 ``[K, bn]`` tiles of
+    one-hot / select intermediates (the select-scatter fold adds one more
+    per value column it keeps live).
+    """
+    k8, v8 = _pad(num_segments, 8), _pad(v, 8)
+    tiles = 3 if use_matmul(reducer, acc_dtype(dtype)) else 4
+    stream = 2 * (8 + v8) * bn * 4
+    acc = 2 * k8 * _pad(v, LANES) * 4
+    return stream + acc + tiles * k8 * bn * 4
+
+
+def _block_grid(fits) -> list[int]:
+    """Lane-aligned power-of-two blocks while ``fits(bn)`` holds; the
+    one-tile block is always offered."""
+    grid = [LANES]
+    while grid[-1] < MAX_BLOCK and fits(2 * grid[-1]):
+        grid.append(2 * grid[-1])
+    return grid
+
+
 def segment_block_candidates(
     n: int, num_segments: int, v: int, reducer: str = "sum",
     dtype=jnp.float32, vmem_budget: int = VMEM_BUDGET,
@@ -112,49 +155,53 @@ def segment_block_candidates(
     """Every ``block_n`` the dense-kernel tuner considers, with its score.
 
     Returns ``[(block_n, working_set_bytes), ...]`` in ascending block order:
-    power-of-two blocks from 8 up to 2048 whose per-step working set fits the
-    budget (the minimum block 8 is always offered).  Working set per block
-    row: ``(K + V) * 4`` bytes for the one-hot-matmul strategy (onehot
-    ``[bn, K]`` + vals ``[bn, V]``, both f32) or ``K * V * 4`` for the
-    select-scatter fold (masked ``[bn, K, V]``).
+    power-of-two blocks from one 128-lane tile up to ``MAX_BLOCK`` whose
+    ``segment_working_set`` fits the budget (the one-tile block is always
+    offered).
     """
-    per_row = (
-        (num_segments + v) * 4
-        if use_matmul(reducer, acc_dtype(dtype))
-        else num_segments * max(v, 1) * 4
-    )
-    cands = [(8, 8 * per_row)]
-    bn = 8
-    while bn < 2048 and (2 * bn) * per_row <= vmem_budget:
-        bn *= 2
-        cands.append((bn, bn * per_row))
-    return cands
+    def ws(bn):
+        return segment_working_set(bn, num_segments, v, reducer, dtype)
+
+    return [(bn, ws(bn)) for bn in _block_grid(lambda b: ws(b) <= vmem_budget)]
 
 
 def choose_block_n(
     n: int, num_segments: int, v: int, reducer: str = "sum",
     dtype=jnp.float32, vmem_budget: int = VMEM_BUDGET,
 ) -> int:
-    """Largest candidate block that fits, clamped to the stream length —
-    exactly the pre-PR-8 greedy tuner, now a pick over the shared grid."""
+    """Largest candidate block that fits, clamped to the stream's own
+    lane-padded length."""
     bn = segment_block_candidates(
         n, num_segments, v, reducer, dtype, vmem_budget
     )[-1][0]
-    return max(8, min(bn, max(8, n)))
+    return min(bn, _pad(n, LANES))
 
 
 def hash_working_set(
     cap: int, bn: int, v: int, reducer: str = "sum", dtype=jnp.float32
 ) -> int:
-    """Bytes resident per probe round of the hash kernel at ``(cap, bn)``:
-    the ``[C, V]`` + ``[C]`` table plus ~4 ``[bn, C]`` probe intermediates
-    (matmul strategy) or the ``[bn, C, V]`` select-scatter fold."""
-    table = cap * (max(v, 1) + 1) * 4
-    if use_matmul(reducer, acc_dtype(dtype)):
-        per_round = 4 * bn * cap * 4 + bn * max(v, 1) * 4
-    else:
-        per_round = bn * cap * max(v, 1) * 4 + 2 * bn * cap * 4
-    return table + per_round
+    """VMEM bytes resident per probe round of the hash kernel at
+    ``(cap, bn)``, padded as Mosaic lays them out: the table's input and
+    output copies (``[C, 1]`` keys and ``[C, V]`` values, each at least a
+    lane tile wide), the double-buffered ``[1, bn]`` / ``[V, bn]`` stream
+    blocks, and ~6 ``[C, bn]`` probe tiles (one-hot, gathered keys, claim,
+    match, and the deposit's select or matmul operand)."""
+    c8, v8 = _pad(cap, 8), _pad(v, 8)
+    table = 2 * c8 * (LANES + _pad(v, LANES)) * 4
+    stream = 2 * (8 + v8) * bn * 4
+    return table + stream + 6 * c8 * bn * 4
+
+
+def hash_block_n(
+    cap: int, n: int, v: int, reducer: str = "sum", dtype=jnp.float32,
+    vmem_budget: int = VMEM_BUDGET,
+) -> int:
+    """Largest lane-aligned block whose probe round fits the budget at
+    table capacity ``cap``, clamped to the stream's lane-padded length."""
+    grid = _block_grid(
+        lambda b: hash_working_set(cap, b, v, reducer, dtype) <= vmem_budget
+    )
+    return min(grid[-1], _pad(n, LANES))
 
 
 def choose_probe_depth(n: int, table_cap: int) -> int:
@@ -187,9 +234,8 @@ def hash_table_candidates(
 
     Returns ``[(table_cap, block_n, max_probes, working_set_bytes), ...]``:
     the capacity is fixed first (load factor ≤ 0.5 over the distinct-key
-    bound, power of two, shrunk until the minimum block fits the budget),
-    then every power-of-two block that keeps the *next doubling* in budget
-    is offered — the same frontier the pre-PR-8 greedy loop walked.
+    bound, power of two, shrunk until the one-tile block fits the budget),
+    then every lane-aligned power-of-two block that fits is offered.
     """
     distinct = min(n, distinct_hint) if distinct_hint else n
     cap = 128
@@ -199,16 +245,13 @@ def hash_table_candidates(
     def fits(cap_: int, bn_: int) -> bool:
         return hash_working_set(cap_, bn_, v, reducer, dtype) <= vmem_budget
 
-    while cap > 128 and not fits(cap, 8):
+    while cap > 128 and not fits(cap, LANES):
         cap //= 2
-    cands = [(cap, 8, choose_probe_depth(n, cap),
-              hash_working_set(cap, 8, v, reducer, dtype))]
-    bn = 8
-    while bn < 1024 and bn < n and fits(cap, 2 * bn):
-        bn *= 2
-        cands.append((cap, bn, choose_probe_depth(n, cap),
-                      hash_working_set(cap, bn, v, reducer, dtype)))
-    return cands
+    probes = choose_probe_depth(n, cap)
+    return [
+        (cap, bn, probes, hash_working_set(cap, bn, v, reducer, dtype))
+        for bn in _block_grid(lambda b: fits(cap, b))
+    ]
 
 
 def choose_table_cap(
@@ -221,13 +264,12 @@ def choose_table_cap(
     vmem_budget: int = VMEM_BUDGET,
 ) -> tuple[int, int, int]:
     """(table_cap, block_n, max_probes): the largest-block candidate from the
-    shared grid, clamped to the stream length — exactly the pre-PR-8 greedy
-    tuner."""
+    shared grid, clamped to the stream's lane-padded length."""
     cap, bn, probes, _ = hash_table_candidates(
         n, v, reducer, dtype, distinct_hint=distinct_hint,
         vmem_budget=vmem_budget,
     )[-1]
-    return cap, max(8, min(bn, max(8, n))), probes
+    return cap, min(bn, _pad(n, LANES)), probes
 
 
 def next_capacity(cap: int, *, limit: int = 1 << 20) -> int | None:

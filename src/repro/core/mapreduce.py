@@ -529,6 +529,27 @@ def _local_view(kind, source, operands):
     return (operands[0][0], operands[1][0])
 
 
+# Dense targets with at most this many keys combine by a fused one-hot
+# reduction rather than XLA's scatter.  A scatter folds every duplicate of a
+# key serially into one accumulator (an f32 count stops growing at 2**24)
+# and, for a narrow ``[N, V]`` value row, asks the TPU for a row-major copy
+# padded to 128 lanes — 21x the bytes at V=6.  The one-hot reduction reads
+# the pairs once, in whatever layout XLA picked, and sums them as a tree.
+ONEHOT_MAX_KEYS = 64
+
+
+def dense_segment(red: Reducer, vals: Array, ids: Array, k: int) -> Array:
+    """Reduce ``vals [N, ...]`` by ``ids [N]`` into a dense ``[k, ...]``
+    (XLA, no kernel); ids outside ``[0, k)`` are dropped."""
+    if k <= ONEHOT_MAX_KEYS and red.axis_reduce is not None:
+        hit = ids[:, None] == jnp.arange(k, dtype=ids.dtype)  # [N, k]
+        hit = hit.reshape(hit.shape + (1,) * (vals.ndim - 1))
+        ident = red.identity(vals.dtype)
+        return red.axis_reduce(jnp.where(hit, vals[:, None], ident), axis=0)
+    safe = jnp.where((ids >= 0) & (ids < k), ids, k)
+    return red.segment(vals, safe, k + 1)[:k]
+
+
 def dense_shard_stage(
     kind, source, mapper, red, target, engine, wire, n_shards,
     with_stats=True, feedback=False, collect=True, tuned=None, hier=False,
@@ -641,12 +662,8 @@ def dense_shard_stage(
                         dmask & (dkeys >= 0) & (dkeys < K)
                     ).astype(jnp.int32)
                 else:
-                    # eager, or a custom reducer without a kernel impl:
-                    # XLA's segmented reduce.
-                    ids = jnp.where(
-                        dmask & (dkeys >= 0) & (dkeys < K), dkeys, K
-                    )
-                    seg = red.segment(dvals, ids, K + 1)[:K]
+                    # eager, or a custom reducer without a kernel impl.
+                    seg = dense_segment(red, dvals, jnp.where(dmask, dkeys, -1), K)
                 partial = red.combine(partial, seg.astype(target_dtype))
             if not collect:
                 total = partial  # caller runs the (possibly batched) collective
@@ -665,8 +682,7 @@ def dense_shard_stage(
             gk = coll.all_gather_tiled(keys)
             gv = coll.all_gather_tiled(vals)
             gm = coll.all_gather_tiled(valid)
-            ids_g = jnp.where(gm & (gk >= 0) & (gk < K), gk, K)
-            total = red.segment(gv, ids_g, K + 1)[:K]
+            total = dense_segment(red, gv, jnp.where(gm, gk, -1), K)
         return total, live, kernel_pairs, residual
 
     return stage, kernel_meta
@@ -913,7 +929,7 @@ def hash_shard_stage(
                 # pinned (only offered when key_range bounds the distinct
                 # keys, so the pinned capacity cannot overflow).
                 cap = tuned.table_cap
-                bn = max(8, min(tuned.block_n or 8, max(8, n_emit)))
+                bn = tuned.block_n
                 probes = min(cap, tuned.probe_depth or
                              HK.choose_probe_depth(n_emit, cap))
             else:

@@ -1061,6 +1061,7 @@ class Program:
         # dispatch (run_stream) instead of being baked into the cache entry
         self._stream_state: dict = {}
         self._last_sig = None  # signature of the most recent dispatch
+        self._last_args = None  # its arguments, for compiled_text()
         self.plan: Plan | None = None  # most recently built plan
         self.stats = ProgramStats()
         self.feedback_slots = 0  # error-feedback residual slots (int8 sums)
@@ -1172,10 +1173,9 @@ class Program:
                 wall = time.perf_counter() - t0
             except faults.InjectedFault as e:
                 # A faulted candidate is simply not measured — tuning is an
-                # optimisation, so the fault is absorbed, never retried.
+                # optimisation, so the fault is absorbed, never retried.  A
+                # real exception propagates: it is a fault, not a slow config.
                 faults.record("absorbed", e)
-                continue
-            except Exception:
                 continue
             measured += 1
             if best_wall is None or wall < best_wall:
@@ -1337,6 +1337,15 @@ class Program:
         self.stats.compiles += 1
         self._session.stats.program_compiles += 1
         return entry
+
+    def compiled_text(self) -> str:
+        """HLO text of the executable the most recent dispatch ran, as the
+        backend compiled it — a Pallas kernel shows up as a
+        ``tpu_custom_call`` on the chip."""
+        if self._last_args is None or self._last_sig not in self._cache:
+            raise ValueError("program has no live executable — dispatch it")
+        fn, _ = self._cache[self._last_sig]
+        return fn.lower(*self._last_args).compile().as_text()
 
     @property
     def plan_hash(self) -> str | None:
@@ -1504,13 +1513,15 @@ class Program:
             if stream_keys
             else []
         )
-        out, new_residuals, new_hash = fn(
+        args = (
             state, jnp.asarray(n_iters, jnp.int32), *residuals, *flat_hash,
             *flat_stream, *operands,
         )
+        out, new_residuals, new_hash = fn(*args)
         self._residual_state[key] = new_residuals
         self._hash_state[key] = (hash_keys, tuple(new_hash))
         self._last_sig = key
+        self._last_args = args
         self.stats.dispatches += 1
         self.stats.iterations += int(n_iters)
         self._session.stats.dispatches += 1

@@ -349,16 +349,17 @@ class BlazeSession:
         The recovery state machine (see docs/architecture.md):
 
         * ``faults.FatalFault`` — recorded and re-raised immediately;
-        * a *kernel* fault (injected ``kernel.*``, or any real exception
-          while a pallas node is live) — if ``program`` is given and still
-          has pallas nodes, those nodes are demoted to eager
+        * an injected *kernel* fault (``kernel.*``) — if ``program`` is given
+          and still has pallas nodes, those nodes are demoted to eager
           (``program.degrade()``) and the dispatch re-attempted.  Live carry
           is preserved: all fault points fire before the executable runs, so
           the retry replays the exact same block;
         * any other ``faults.TransientFault`` — re-attempted up to
           ``retry.attempts`` times with exponential backoff, bounded by
           ``retry.deadline_s``; exhaustion records the fault as fatal and
-          re-raises.
+          re-raises;
+        * a real (non-injected) exception propagates untouched: a kernel the
+          compiler refuses is a fault to see, not a node to run elsewhere.
 
         Every injected fault is recorded in ``faults.registry`` under exactly
         one disposition, so the chaos suite's conservation law
@@ -377,17 +378,12 @@ class BlazeSession:
             except faults.FatalFault as e:
                 faults.record("fatal", e)
                 raise
-            except Exception as e:  # noqa: BLE001 — classified below
-                transient = isinstance(e, faults.TransientFault)
-                kernel = transient and e.point.startswith("kernel.")
-                real = not isinstance(e, faults.InjectedFault)
-                if (kernel or real) and program is not None:
+            except faults.TransientFault as e:
+                if e.point.startswith("kernel.") and program is not None:
                     if program.degrade() > 0:
                         faults.record("degraded", e)
                         self.stats.degraded_nodes += 1
                         continue
-                if not transient:
-                    raise
                 tries += 1
                 deadline_hit = (
                     policy.deadline_s is not None
@@ -403,7 +399,7 @@ class BlazeSession:
                 delay *= policy.multiplier
 
     def _degrade_op_node(self, node, e) -> None:
-        """Demote a per-op node to eager after a kernel fault.
+        """Demote a per-op node to eager after an injected kernel fault.
 
         The tune_key lands in ``self._degraded`` so every later build of the
         same logical node (per-op, program, serve) is born degraded; the
@@ -442,15 +438,10 @@ class BlazeSession:
             except faults.FatalFault as e:
                 faults.record("fatal", e)
                 raise
-            except Exception as e:  # noqa: BLE001 — classified below
-                transient = isinstance(e, faults.TransientFault)
-                kernel = transient and e.point.startswith("kernel.")
-                real = not isinstance(e, faults.InjectedFault)
-                if (kernel or real) and node.engine == "pallas":
+            except faults.TransientFault as e:
+                if e.point.startswith("kernel.") and node.engine == "pallas":
                     self._degrade_op_node(node, e)
                     continue
-                if not transient:
-                    raise
                 tries += 1
                 deadline_hit = (
                     policy.deadline_s is not None
@@ -628,10 +619,9 @@ class BlazeSession:
             except faults.InjectedFault as e:
                 # A faulted measurement just loses the race — the candidate
                 # is skipped, nothing retries, and the ledger records the
-                # injection as absorbed.
+                # injection as absorbed.  A real exception propagates: a
+                # candidate that cannot run is a fault, not a slow config.
                 faults.record("absorbed", e)
-                continue
-            except Exception:  # noqa: BLE001 — a failed candidate just loses
                 continue
             measured += 1
             self.stats.compiles += st.compiles + st2.compiles

@@ -36,8 +36,8 @@ keys identically.
 
 ``choose_table_cap`` autotunes (capacity, block size, probe depth) under a
 VMEM budget; ``interpret=None`` resolves via ``pallas_interpret_default`` —
-interpret off-TPU, forced either way by ``BLAZE_PALLAS_INTERPRET`` — so CPU
-CI runs the exact kernel program TPUs run.
+interpret exactly when the backend is not a TPU — so CPU CI runs the exact
+kernel program TPUs run.
 """
 from __future__ import annotations
 
@@ -50,11 +50,13 @@ from jax.experimental import pallas as pl
 
 from repro.kernels.segment_reduce import (
     _acc_dtype,
-    _combine,
-    _fold,
     _identity,
     _use_matmul,
+    lane_fold,
+    onehot_matmul,
     pallas_interpret_default,
+    segment_reduce_lanes,
+    select_scatter,
 )
 
 REDUCERS = ("sum", "prod", "min", "max")
@@ -107,82 +109,93 @@ def hash32(x: jax.Array) -> jax.Array:
     return x ^ (x >> 16)
 
 
+def _srl(x, n: int):
+    return jax.lax.shift_right_logical(x, jnp.int32(n))
+
+
+def home_slot(keys: jax.Array, cap: int) -> jax.Array:
+    """``hash32(keys) % cap`` in int32 arithmetic only (the kernel has no
+    unsigned vectors): the same splitmix32 bits, wrapped multiplies, and an
+    unsigned modulus rebuilt from a signed one on ``bits >> 1``."""
+    x = keys.astype(jnp.int32)
+    x = (x ^ _srl(x, 16)) * jnp.int32(0x7FEB352D)
+    x = (x ^ _srl(x, 15)) * jnp.int32(0x846CA68B - (1 << 32))
+    x = x ^ _srl(x, 16)
+    if cap & (cap - 1) == 0:
+        return x & (cap - 1)
+    return (2 * (_srl(x, 1) % cap) + (x & 1)) % cap
+
+
 def _hash_kernel(
     keys_ref, vals_ref, ikeys_ref, ivals_ref, iovf_ref,
-    okeys_ref, ovals_ref, oovf_ref, *, cap, bn, probes, reducer, acc_dtype,
+    okeys_ref, ovals_ref, oovf_ref, *, cap, probes, reducer,
 ):
     step = pl.program_id(0)
+    acc_dtype = ovals_ref.dtype
 
     @pl.when(step == 0)
     def _init():
         okeys_ref[...] = ikeys_ref[...]
-        ovals_ref[...] = ivals_ref[...].astype(acc_dtype)
+        ovals_ref[...] = ivals_ref[...]
         oovf_ref[...] = iovf_ref[...]
 
-    keys = keys_ref[...]  # [bn] int32; EMPTY_KEY marks a dead lane
-    vals = vals_ref[...].astype(acc_dtype)  # [bn, V]
+    keys = keys_ref[...]  # [1, bn] int32; EMPTY_KEY marks a dead lane
+    vals = vals_ref[...]  # [V, bn] acc dtype
+    v, bn = vals.shape
     ident = _identity(reducer, acc_dtype)
-    active0 = keys != EMPTY_KEY
-    if _use_matmul(reducer, acc_dtype):
-        # Zero dead-lane values up front: an all-False one-hot row still
+    matmul = _use_matmul(reducer, acc_dtype)
+    if matmul:
+        # Zero dead-lane values up front: an all-zero one-hot column still
         # contracts 0·NaN = NaN into every slot (same hazard as the dense
         # kernel).
-        vals = jnp.where(active0[:, None], vals, 0)
-    h = (hash32(keys) % jnp.uint32(cap)).astype(jnp.int32)
-    iota_c = jax.lax.broadcasted_iota(jnp.int32, (bn, cap), 1)
+        vals = jnp.where(jnp.broadcast_to(keys, (v, bn)) != EMPTY_KEY, vals, 0)
+    iota_c = jax.lax.broadcasted_iota(jnp.int32, (cap, bn), 0)
 
-    def gather_slot_keys(tkeys, onehot):
-        # tkeys[slot_i] for every lane, without dynamic indexing: a masked
-        # max over the table axis (EMPTY_KEY = int32 min is the floor).
-        return jnp.max(
-            jnp.where(onehot, tkeys[None, :], EMPTY_KEY), axis=1
-        )
+    def slot_keys(onehot):
+        # tkeys[slot] for every lane, without dynamic indexing: a masked max
+        # down the table axis (EMPTY_KEY = int32 min is the floor).
+        tkeys = jnp.broadcast_to(okeys_ref[...], (cap, bn))
+        return jnp.max(jnp.where(onehot, tkeys, EMPTY_KEY), axis=0,
+                       keepdims=True)  # [1, bn]
 
     def probe_round(carry):
-        r, tkeys, tvals, active = carry
-        slot = (h + r) % cap  # [bn]
-        onehot = slot[:, None] == iota_c  # [bn, C]
-        slot_key = gather_slot_keys(tkeys, onehot)
+        r, slot, active = carry  # active [1, bn] int32: 1 = still unplaced
+        onehot = slot == iota_c  # [C, bn]
 
         # Claim free slots: winner per slot = max key among claimants —
         # deterministic, and the same tie-break hashmap_insert uses.
-        want = active & (slot_key == EMPTY_KEY)
-        claim = jnp.max(
-            jnp.where(onehot & want[:, None], keys[:, None], EMPTY_KEY),
-            axis=0,
-        )  # [C]
-        tkeys = jnp.where(
-            (tkeys == EMPTY_KEY) & (claim != EMPTY_KEY), claim, tkeys
-        )
+        want = (active != 0) & (slot_keys(onehot) == EMPTY_KEY)
+        claim = lane_fold(
+            jnp.where(onehot, jnp.where(want, keys, EMPTY_KEY), EMPTY_KEY),
+            "max",
+        )  # [C, 1]
+        tkeys = okeys_ref[...]
+        okeys_ref[...] = jnp.where(tkeys == EMPTY_KEY, claim, tkeys)
 
         # Deposit where our key is now resident at our slot.  Duplicate keys
         # in the block all match the same row and are folded together by the
         # monoid — the kernel subsumes unique_combine.
-        slot_key = gather_slot_keys(tkeys, onehot)
-        deposit = active & (slot_key == keys)
-        match = onehot & deposit[:, None]  # [bn, C]
-        if _use_matmul(reducer, acc_dtype):
-            tvals = tvals + jax.lax.dot_general(
-                match.astype(acc_dtype), vals,
-                (((0,), (0,)), ((), ())),
-                preferred_element_type=acc_dtype,
-            )
+        deposit = (active != 0) & (slot_keys(onehot) == keys)
+        match = jnp.where(deposit, slot, -1) == iota_c  # [C, bn]
+        if matmul:
+            ovals_ref[...] += onehot_matmul(match, vals)
         else:
-            masked = jnp.where(match[:, :, None], vals[:, None, :], ident)
-            tvals = _combine(reducer)(tvals, _fold(reducer)(masked, axis=0))
-        return r + 1, tkeys, tvals, active & ~deposit
+            ovals_ref[...] = select_scatter(
+                ovals_ref[...], match, vals, reducer, ident
+            )
+        slot = jnp.where(slot + 1 == cap, 0, slot + 1)
+        return r + 1, slot, jnp.where(deposit, 0, active)
 
     def keep_probing(carry):
-        r, _, _, active = carry
-        return (r < probes) & jnp.any(active)
+        r, _, active = carry
+        return (r < probes) & (lane_fold(active, "max")[0, 0] > 0)
 
-    _, tkeys, tvals, active = jax.lax.while_loop(
+    active0 = jnp.where(keys != EMPTY_KEY, 1, 0)
+    _, _, active = jax.lax.while_loop(
         keep_probing, probe_round,
-        (jnp.zeros((), jnp.int32), okeys_ref[...], ovals_ref[...], active0),
+        (jnp.int32(0), home_slot(keys, cap), active0),
     )
-    okeys_ref[...] = tkeys
-    ovals_ref[...] = tvals
-    oovf_ref[...] = oovf_ref[...] + jnp.sum(active).astype(jnp.int32)
+    oovf_ref[...] += lane_fold(active, "sum")
 
 
 @functools.partial(
@@ -209,6 +222,10 @@ def hash_aggregate(
     counts lanes that exhausted ``max_probes`` (plus whatever ``init``
     carried).  ``init=(keys, vals, overflow)`` merges into an existing table
     with the same probe sequence as ``containers.hashmap_insert``.
+
+    The pair stream enters lane-dense (keys ``[1, N]``, values ``[V, N]``)
+    and the table sits down the sublanes (keys ``[C, 1]``, values
+    ``[C, V]``), so each probe round works on ``[C, bn]`` tiles.
     """
     if reducer not in REDUCERS:
         raise ValueError(f"unknown reducer {reducer!r}; supported: {REDUCERS}")
@@ -229,40 +246,44 @@ def hash_aggregate(
         interpret = pallas_interpret_default()
     if max_probes is None:
         max_probes = choose_probe_depth(n, table_cap)
-    if block_n is None:
-        _, block_n, _ = choose_table_cap(n, v, reducer, vals.dtype)
-    bn = min(block_n, n)
-    n_pad = -(-n // bn) * bn
-    keys_p = jnp.pad(keys, (0, n_pad - n), constant_values=EMPTY_KEY)
-    vals_p = jnp.pad(vals, ((0, n_pad - n), (0, 0)))
+    bn, n_pad = hash_aggregate_lanes(
+        n, table_cap, v, reducer, vals.dtype, block_n=block_n
+    )
+    keys_p = jnp.pad(
+        keys.astype(jnp.int32), (0, n_pad - n), constant_values=EMPTY_KEY
+    )
+    vals_t = jnp.pad(vals.astype(acc).T, ((0, 0), (0, n_pad - n)))
 
     kernel = functools.partial(
-        _hash_kernel, cap=table_cap, bn=bn, probes=max_probes,
-        reducer=reducer, acc_dtype=acc,
+        _hash_kernel, cap=table_cap, probes=max_probes, reducer=reducer
     )
+    table = lambda i: (0, 0)  # noqa: E731 — the resident table's block
     tkeys, tvals, ovf = pl.pallas_call(
         kernel,
         grid=(n_pad // bn,),
         in_specs=[
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn, v), lambda i: (i, 0)),
-            pl.BlockSpec((table_cap,), lambda i: (0,)),
-            pl.BlockSpec((table_cap, v), lambda i: (0, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
+            pl.BlockSpec((1, bn), lambda i: (0, i)),
+            pl.BlockSpec((v, bn), lambda i: (0, i)),
+            pl.BlockSpec((table_cap, 1), table),
+            pl.BlockSpec((table_cap, v), table),
+            pl.BlockSpec((1, 1), table),
         ],
         out_specs=(
-            pl.BlockSpec((table_cap,), lambda i: (0,)),
-            pl.BlockSpec((table_cap, v), lambda i: (0, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
+            pl.BlockSpec((table_cap, 1), table),
+            pl.BlockSpec((table_cap, v), table),
+            pl.BlockSpec((1, 1), table),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((table_cap,), jnp.int32),
+            jax.ShapeDtypeStruct((table_cap, 1), jnp.int32),
             jax.ShapeDtypeStruct((table_cap, v), acc),
-            jax.ShapeDtypeStruct((1,), jnp.int32),
+            jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ),
         interpret=interpret,
-    )(keys_p, vals_p, ikeys, ivals, iovf.astype(jnp.int32)[None])
-    return tkeys, tvals, ovf[0]
+    )(
+        keys_p[None, :], vals_t, ikeys[:, None], ivals,
+        iovf.astype(jnp.int32).reshape(1, 1),
+    )
+    return tkeys[:, 0], tvals, ovf[0, 0]
 
 
 def hash_aggregate_lanes(
@@ -270,8 +291,10 @@ def hash_aggregate_lanes(
     block_n: int | None = None,
 ) -> tuple[int, int]:
     """(block_n, padded lane count) one ``hash_aggregate`` pass processes for
-    ``n`` pairs — the static half of the hash-kernel occupancy accounting."""
+    ``n`` pairs into a ``table_cap`` table — the static half of the
+    hash-kernel occupancy accounting."""
     if block_n is None:
-        _, block_n, _ = choose_table_cap(n, v, reducer, dtype)
-    bn = min(block_n, max(n, 1))
-    return bn, -(-max(n, 1) // bn) * bn
+        from repro.core.cost import hash_block_n
+
+        block_n = hash_block_n(table_cap, n, v, reducer, dtype)
+    return segment_reduce_lanes(n, table_cap, v, reducer, dtype, block_n)

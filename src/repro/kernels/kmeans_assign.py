@@ -11,9 +11,9 @@ so the per-cluster Σx and counts — the entire MapReduce payload — accumulat
 in a VMEM-resident ``[K, D+1]`` tile across the sequential grid, and the
 points are read from HBM exactly once.  This is the kernel-level form of the
 paper's eager reduction: emit→reduce fused into the map body.  The scatter
-itself is ``onehot_accumulate`` — the same one-hot-matmul accumulator the
-generalized segment-reduce kernel uses — applied to points with a ones
-column appended, so Σx and the counts come out of a single matmul.
+itself is ``onehot_accumulate`` — a one-hot-matmul accumulator, the pattern
+the generalized segment-reduce kernel also uses — applied to points with a
+ones column appended, so Σx and the counts come out of a single matmul.
 """
 from __future__ import annotations
 
@@ -23,7 +23,23 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.segment_reduce import onehot_accumulate
+
+def onehot_accumulate(ids, vals, k: int, *, valid=None, acc_dtype=jnp.float32):
+    """One-hot-matmul scatter-add: ``[bn]`` ids × ``[bn, V]`` vals → ``[K, V]``.
+
+    Lanes with ``ids`` outside ``[0, k)`` (or ``valid == False``) contribute
+    nothing.
+    """
+    bn = ids.shape[0]
+    iota_k = jax.lax.broadcasted_iota(jnp.int32, (bn, k), 1)
+    onehot = ids[:, None] == iota_k  # [bn, K]
+    if valid is not None:
+        onehot &= valid[:, None]
+    return jax.lax.dot_general(
+        onehot.astype(acc_dtype), vals.astype(acc_dtype),
+        (((0,), (0,)), ((), ())),
+        preferred_element_type=acc_dtype,
+    )  # [K, V]
 
 
 def _kmeans_kernel(pts_ref, ctr_ref, assign_ref, stats_ref, *, k, bn, n_true):

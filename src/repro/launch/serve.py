@@ -88,6 +88,9 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     server = build_server(
         host=args.host, port=args.port, max_queue=args.max_queue,
         per_tenant=args.per_tenant, max_batch=args.max_batch,

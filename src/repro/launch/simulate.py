@@ -73,11 +73,14 @@ def force_host_device_count(n: int) -> None:
 def simulated_env(n: int, base_env=None, *, pythonpath: str | None = None):
     """A subprocess environment simulating ``n`` host devices.
 
-    Copies ``base_env`` (default ``os.environ``), forces the device count in
+    Copies ``base_env`` (default ``os.environ``), pins the child to the CPU
+    backend (``JAX_PLATFORMS=cpu``: a simulated child must never reach for
+    an accelerator its parent may hold), forces the device count in
     ``XLA_FLAGS``, and optionally prepends ``pythonpath`` — the exact recipe
     the multi-device test harnesses spawn workers with.
     """
     env = dict(os.environ if base_env is None else base_env)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = host_device_flags(n, env.get("XLA_FLAGS", ""))
     if pythonpath is not None:
         old = env.get("PYTHONPATH", "")

@@ -25,14 +25,16 @@ from repro.kernels import segment_reduce as SK
 def test_choose_block_n_is_grid_pick(reducer, dtype, k, v):
     for n in (1, 7, 100, 5000):
         grid = cost.segment_block_candidates(n, k, v, reducer, dtype)
-        # ascending powers of two starting at 8, scored within budget
+        # ascending powers of two from one 128-lane tile, within budget
         assert [bn for bn, _ in grid] == sorted({bn for bn, _ in grid})
-        assert grid[0][0] == 8
+        assert grid[0][0] == cost.LANES
         for bn, ws in grid[1:]:
             assert bn & (bn - 1) == 0 and ws <= cost.VMEM_BUDGET
-        # the kernel delegate picks the largest candidate, clamped to n
-        assert SK.choose_block_n(n, k, v, reducer, dtype) == max(
-            8, min(grid[-1][0], max(8, n))
+        # the kernel delegate picks the largest candidate, clamped to the
+        # stream's lane-padded length
+        lanes_n = -(-n // cost.LANES) * cost.LANES
+        assert SK.choose_block_n(n, k, v, reducer, dtype) == min(
+            grid[-1][0], lanes_n
         )
 
 
@@ -54,7 +56,8 @@ def test_choose_table_cap_is_grid_pick(reducer, dtype, v):
                 n, v, reducer, dtype, distinct_hint=hint
             )
             cap, bn, probes, _ = grid[-1]
-            assert got == (cap, max(8, min(bn, max(8, n))), probes)
+            lanes_n = -(-n // cost.LANES) * cost.LANES
+            assert got == (cap, min(bn, lanes_n), probes)
 
 
 def test_kernel_delegates_share_one_implementation():
@@ -67,9 +70,13 @@ def test_kernel_delegates_share_one_implementation():
 
 def test_hash_working_set_monotone_in_block():
     ws = [
-        cost.hash_working_set(512, bn, 4) for bn in (8, 16, 32, 64, 128)
+        cost.hash_working_set(512, bn, 4) for bn in (128, 256, 512, 1024)
     ]
     assert ws == sorted(ws)
+    # a narrow trailing dim still occupies a whole 128-lane tile
+    assert cost.hash_working_set(512, 128, 1) == cost.hash_working_set(
+        512, 128, 8
+    )
 
 
 # -- calibrated fallback model == the PR 2 static rule -----------------------

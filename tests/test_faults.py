@@ -238,13 +238,60 @@ def test_hash_kernel_fault_degrades_hash_dispatch():
     _assert_balanced(degraded=1)
 
 
-def _pallas_step(src):
+def _refused_sum():
+    """``sum`` whose segment kernel raises as the TPU compiler does when it
+    refuses a kernel — a real exception, not an injected fault."""
+    import dataclasses
+
+    from repro.core.reducers import SUM
+
+    def refused(*a, **kw):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    return dataclasses.replace(SUM, pallas_segment=refused)
+
+
+def test_real_kernel_exception_propagates_per_op():
+    sess = _sess()
+    src = sess.distribute(np.arange(64, dtype=np.float32))
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        sess.map_reduce(
+            src, _sq_mapper, _refused_sum(), jnp.zeros((8,), jnp.float32),
+            engine="pallas",
+        )
+    assert sess.stats.degraded_nodes == 0 and sess.stats.retries == 0
+    _assert_balanced(degraded=0)
+
+
+def test_real_kernel_exception_propagates_from_program():
+    sess = _sess()
+    src = sess.distribute(np.arange(64, dtype=np.float32))
+    prog = sess.program(_pallas_step(src, _refused_sum()))
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        sess.run_loop(prog, jnp.ones((1,), jnp.float32), max_iters=2)
+    assert sess.stats.degraded_nodes == 0 and sess.stats.retries == 0
+    assert prog.stats.dispatches == 0
+
+
+def test_real_tuning_candidate_exception_propagates():
+    sess = _sess()
+    src = sess.distribute(np.arange(256, dtype=np.float32))
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        sess.map_reduce(
+            src, _sq_mapper, _refused_sum(), jnp.zeros((8,), jnp.float32),
+            tune=True,
+        )
+    assert sess.stats.degraded_nodes == 0
+    _assert_balanced(absorbed=0)
+
+
+def _pallas_step(src, reducer="sum"):
     def step(ctx, state):
         def mapper(i, x, emit, env):
             emit(jnp.asarray(x, jnp.int32) % 8, x * env[0])
 
         s = ctx.map_reduce(
-            src, mapper, "sum", jnp.zeros((8,), jnp.float32),
+            src, mapper, reducer, jnp.zeros((8,), jnp.float32),
             engine="pallas", env=state,
         )
         return state * 0.5 + s[:1] * 1e-3

@@ -168,10 +168,10 @@ def test_segment_reduce_autotune_and_lanes():
         segment_reduce_lanes,
     )
 
-    # tiny working sets → max block; huge K → small block; floor respected
+    # tiny working sets → max block; huge K → one lane tile; floor respected
     assert choose_block_n(100_000, 8, 4) == 2048
-    assert choose_block_n(100_000, 20_000, 1, "sum", np.int32) <= 64
-    assert choose_block_n(5, 8, 4) >= 8
+    assert choose_block_n(100_000, 20_000, 1, "sum", np.int32) == 128
+    assert choose_block_n(5, 8, 4) == 128
     bn, lanes = segment_reduce_lanes(1000, 8, 4)
     assert lanes % bn == 0 and lanes >= 1000
     # autotuned call agrees with the oracle

@@ -9,12 +9,13 @@ import sys
 
 import pytest
 
+from repro.launch.simulate import simulated_env
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _run(code: str) -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env = simulated_env(8)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     out = subprocess.run(
         [sys.executable, "-c", code], capture_output=True, text=True, env=env,

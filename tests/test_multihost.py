@@ -67,6 +67,7 @@ def test_simulated_env_is_the_worker_recipe():
     assert "--xla_force_host_platform_device_count=8" in env["XLA_FLAGS"]
     assert "--xla_cpu_foo=1" in env["XLA_FLAGS"]
     assert env["PYTHONPATH"].split(os.pathsep) == ["/src", "/elsewhere"]
+    assert env["JAX_PLATFORMS"] == "cpu"  # never contends for a chip
     assert base == {"XLA_FLAGS": "--xla_cpu_foo=1", "PYTHONPATH": "/elsewhere"}
 
 

@@ -41,9 +41,11 @@ def _tok_mapper(i, toks, emit):
 
 
 def test_compat_imports_on_installed_jax():
-    # The seed failed `import repro.core` on JAX 0.4.x; the shim must resolve.
+    # The compat names are plain aliases of the installed JAX 0.9 surface.
+    import jax
+
     import repro.core  # noqa: F401
-    from repro.compat import (  # noqa: F401
+    from repro.compat import (
         AxisType,
         get_abstract_mesh,
         make_mesh,
@@ -51,17 +53,22 @@ def test_compat_imports_on_installed_jax():
         shard_map,
     )
 
-    assert callable(shard_map)
+    assert shard_map is jax.shard_map
+    assert make_mesh is jax.make_mesh and set_mesh is jax.set_mesh
+    assert AxisType is jax.sharding.AxisType
+    assert get_abstract_mesh is jax.sharding.get_abstract_mesh
 
 
 def test_compat_shard_map_accepts_either_check_flag():
+    # JAX 0.9 spells the replication check ``check_vma``; either setting
+    # (and the default) maps the same function.
     from jax.sharding import PartitionSpec as P
 
     from repro.compat import shard_map
 
     mesh = data_mesh()
     x = jnp.arange(8, dtype=jnp.float32)
-    for kw in ({"check_vma": False}, {"check_rep": False}, {}):
+    for kw in ({"check_vma": False}, {"check_vma": True}, {}):
         f = shard_map(
             lambda v: v * 2, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
             **kw,
@@ -75,7 +82,57 @@ def test_compat_make_mesh_and_set_mesh():
     mesh = make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     assert mesh.axis_names == ("data",)
     with set_mesh(mesh):
-        pass  # context form works on every JAX
+        from repro.compat import get_abstract_mesh
+
+        assert get_abstract_mesh().axis_names == ("data",)
+
+
+# -- persistent compile cache --------------------------------------------------
+
+
+@pytest.fixture
+def restore_compile_cache():
+    """Put JAX's persistent-cache settings back after a test changes them."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs")
+    before = {n: getattr(jax.config, n) for n in names}
+    yield
+    for n, v in before.items():
+        jax.config.update(n, v)
+    compilation_cache.reset_cache()
+
+
+def test_compile_cache_uses_env_dir(tmp_path, monkeypatch, restore_compile_cache):
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+    # a compile now lands in that directory
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    compilation_cache.reset_cache()
+    jax.jit(lambda x: x * 3 + 1)(jnp.arange(7)).block_until_ready()
+    assert any(tmp_path.iterdir())
+
+
+def test_compile_cache_default_is_checkout_dir(monkeypatch, restore_compile_cache):
+    import os
+
+    import jax
+
+    from repro.launch import compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(root, ".jax_cache")
+    assert compile_cache.enable_compile_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
 
 
 # -- executable reuse ----------------------------------------------------------
