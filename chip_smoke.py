@@ -32,8 +32,9 @@ with its reference, or when any phase raises.
 
   python chip_smoke.py              # one chip: data_mesh(1), all phases
   python chip_smoke.py --chips 4    # the three jobs on data_mesh(4) only;
-                                    # word counts also on data_mesh(1), and
-                                    # the two must be bit-equal
+                                    # the word count also once on
+                                    # data_mesh(1), and the tables must be
+                                    # bit-equal
 
 On one host the mesh has one node, so the hierarchical-collectives pass has
 nothing to rewrite here: ``--chips 4`` exercises the flat data mesh
@@ -245,7 +246,9 @@ def _count_words(mesh, lines, sz: Sizes, engine: str, clock):
     }
 
 
-def phase_wordcount(meshes, sz: Sizes, seed: int, clock) -> dict:
+def phase_wordcount(counts, sz: Sizes, seed: int, clock) -> dict:
+    """Word count for each ``(mesh, engine)`` in ``counts``; every table must
+    equal the corpus's true counts and every other table."""
     from repro.data.synthetic import zipf_corpus
 
     lines, true_counts = zipf_corpus(
@@ -253,14 +256,13 @@ def phase_wordcount(meshes, sz: Sizes, seed: int, clock) -> dict:
     )
     want = {w: int(c) for w, c in enumerate(true_counts) if c}
     runs, tables = [], []
-    for mesh in meshes:
-        for engine in ("eager", "pallas"):
-            got, overflow, info = _count_words(mesh, lines, sz, engine, clock)
-            check(overflow == 0, f"word count ({engine}) overflowed {overflow}")
-            check(got == want, f"word count ({engine}, {mesh.size} shards) "
-                  "differs from the corpus's true counts")
-            tables.append(got)
-            runs.append(info)
+    for mesh, engine in counts:
+        got, overflow, info = _count_words(mesh, lines, sz, engine, clock)
+        check(overflow == 0, f"word count ({engine}) overflowed {overflow}")
+        check(got == want, f"word count ({engine}, {mesh.size} shards) "
+              "differs from the corpus's true counts")
+        tables.append(got)
+        runs.append(info)
     check(all(t == tables[0] for t in tables), "word counts differ by mesh")
     return {"lines": sz.n_lines, "words": int(true_counts.sum()),
             "vocab": sz.vocab, "block_lines": sz.block_lines, "runs": runs}
@@ -340,13 +342,18 @@ def run_phase(name: str, fn, *args) -> bool:
     return ok
 
 
-def run(mesh_sizes, sz: Sizes, seed: int, *, serve: bool) -> bool:
-    """All phases on ``data_mesh(n)`` for each n in ``mesh_sizes`` (the
-    last one runs PageRank and k-means); True when every phase passed."""
+def run(n_shards: int, sz: Sizes, seed: int, *, serve: bool) -> bool:
+    """Every job on ``data_mesh(n_shards)``, and the server when ``serve``;
+    True when every phase passed.  On more than one shard the word count
+    also runs once on ``data_mesh(1)`` (the fast pallas engine), the table
+    the sharded counts must equal bit for bit."""
     from repro.core import data_mesh
 
     clock = CompileClock()
-    meshes = [data_mesh(n) for n in mesh_sizes]
+    mesh = data_mesh(n_shards)
+    counts = [(mesh, "eager"), (mesh, "pallas")]
+    if n_shards > 1:
+        counts.insert(0, (data_mesh(1), "pallas"))
     words = sz.n_lines * sz.words_per_line * 3 // 4
     print(json.dumps({
         "note": f"word count cut to ~{words / 1e6:.1f} M words in "
@@ -354,9 +361,9 @@ def run(mesh_sizes, sz: Sizes, seed: int, *, serve: bool) -> bool:
                 f"{words / PAPER_WORDS:.3f} of the paper's 0.4 B, for host "
                 "generation time",
     }), flush=True)
-    ok = run_phase("pagerank", phase_pagerank, meshes[-1], sz, seed, clock)
-    ok &= run_phase("kmeans", phase_kmeans, meshes[-1], sz, seed, clock)
-    ok &= run_phase("wordcount", phase_wordcount, meshes, sz, seed, clock)
+    ok = run_phase("pagerank", phase_pagerank, mesh, sz, seed, clock)
+    ok &= run_phase("kmeans", phase_kmeans, mesh, sz, seed, clock)
+    ok &= run_phase("wordcount", phase_wordcount, counts, sz, seed, clock)
     if serve:
         ok &= run_phase("serve", phase_serve, sz, seed, clock)
     return ok
@@ -386,10 +393,7 @@ def main(argv=None) -> int:
     print(json.dumps({"compile_cache": enable_compile_cache(),
                       "devices": len(devices)}), flush=True)
     t0 = time.perf_counter()
-    if args.chips == 4:
-        ok = run((1, 4), Sizes(), args.seed, serve=False)
-    else:
-        ok = run((1,), Sizes(), args.seed, serve=True)
+    ok = run(args.chips, Sizes(), args.seed, serve=args.chips == 1)
     print(json.dumps({"total_wall_s": time.perf_counter() - t0}), flush=True)
     if not ok:
         return 1

@@ -306,13 +306,14 @@ def kmeans_reference(
     chunk at a time, so the oracle's memory stays flat in ``len(points)``."""
     centers = init_centers.astype(np.float64).copy()
     k, dim = centers.shape
-    chunk = 1 << 20
+    chunk = 1 << 18
     for it in range(1, max_iters + 1):
         sums = np.zeros((k, dim))
         counts = np.zeros(k, np.int64)
         for lo in range(0, len(points), chunk):
             x = points[lo:lo + chunk].astype(np.float64)
-            d2 = ((x[:, None, :] - centers[None, :, :]) ** 2).sum(-1)
+            # one centre at a time: no [chunk, K, dim] temporary
+            d2 = np.stack([((x - c) ** 2).sum(1) for c in centers], 1)
             assign = d2.argmin(1)
             counts += np.bincount(assign, minlength=k)
             for j in range(dim):
