@@ -83,6 +83,7 @@ from repro.core import containers as C
 from repro.core import faults
 from repro.core import mapreduce as _mr
 from repro.core import plan as plan_mod
+from repro.core import tracing
 from repro.core.plan import (
     ContainerOpNode,
     DEFAULT_PASSES,
@@ -1060,6 +1061,8 @@ class Program:
         # out-of-core sources whose (data, base) operands arrive per
         # dispatch (run_stream) instead of being baked into the cache entry
         self._stream_state: dict = {}
+        # signatures whose current executable has run (so has compiled)
+        self._ran: set = set()
         self._last_sig = None  # signature of the most recent dispatch
         self._last_args = None  # its arguments, for compiled_text()
         self.plan: Plan | None = None  # most recently built plan
@@ -1408,6 +1411,7 @@ class Program:
                     n += 1
             if hit:
                 self._cache.pop(key, None)
+                self._ran.discard(key)
         return n
 
     # -- carry export/restore (epoch-granular resume) -------------------------
@@ -1485,8 +1489,22 @@ class Program:
         block per dispatch via ``stream_blocks`` — a dict mapping each
         stream-source key to its ``(data, base)`` device operands.  Use
         :meth:`run_stream` rather than passing this by hand.
+
+        The host's part is a ``blaze.dispatch`` span (its seconds in
+        ``SessionStats.dispatch_s``), or ``blaze.compile`` for the first
+        call of a newly built executable, where jit compiles.
         """
         key = _mr._abstract(state)
+        if key in self._ran:
+            with tracing.span("dispatch", self._session.stats, "dispatch_s"):
+                return self._dispatch(key, state, n_iters, stream_blocks)
+        with tracing.span("compile") as sp:
+            out = self._dispatch(key, state, n_iters, stream_blocks)
+            sp.set_metadata(plan_hash=self.plan_hash)
+        self._ran.add(key)
+        return out
+
+    def _dispatch(self, key, state, n_iters, stream_blocks):
         fn, operands = self._build(state)
         # Fault points fire BEFORE the executable runs or any carry is
         # written back, so a supervised retry of this dispatch is exact.
@@ -1589,11 +1607,12 @@ class Program:
         bytes_per_block = sum(src.block_nbytes for src in stream_sources)
 
         def produce(b):
-            views = {}
-            for sk, src in zip(stream_keys, stream_sources):
-                bv = src.block_view(b, mesh)
-                views[sk] = (bv.data, bv.base)
-            return views
+            with tracing.span("feed.produce"):
+                views = {}
+                for sk, src in zip(stream_keys, stream_sources):
+                    bv = src.block_view(b, mesh)
+                    views[sk] = (bv.data, bv.base)
+                return views
 
         resumed_from = None
         if resume:
@@ -1604,12 +1623,18 @@ class Program:
         blocks = syncs = 0
         converged = False
         supervised = getattr(self._session, "supervised", None)
+        stats = self._session.stats
         while epochs < max_epochs:
             if prefetch:
                 it = prefetch_iter(produce, range(n_blocks), depth=depth)
             else:
                 it = ((b, produce(b)) for b in range(n_blocks))
-            for _b, views in it:
+            while True:
+                with tracing.span("feed.wait", stats, "feed_wait_s"):
+                    nxt = next(it, None)
+                if nxt is None:
+                    break
+                _b, views = nxt
                 if supervised is not None:
                     state = supervised(
                         lambda: self(state, 1, stream_blocks=views),
@@ -1625,9 +1650,11 @@ class Program:
                 if epochs % checkpoint_every == 0:
                     self.save_checkpoint(manager, state, epochs)
             if cond is not None:
-                self._session.stats.host_syncs += 1
+                stats.host_syncs += 1
                 syncs += 1
-                if bool(cond(state)):
+                with tracing.span("sync"):
+                    done = bool(cond(state))
+                if done:
                     converged = True
                     break
         return state, StreamInfo(

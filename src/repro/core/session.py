@@ -40,6 +40,7 @@ from repro.core import cost as cost_mod
 from repro.core import faults
 from repro.core import mapreduce as _mr
 from repro.core import plan as plan_mod
+from repro.core import tracing
 # The engine-resolution policy moved to repro.core.plan in PR 5 (it is the
 # plan optimizer's resolve-engines pass, applied per node); these re-exports
 # keep the long-standing session spellings working.
@@ -79,6 +80,11 @@ class SessionStats:
     retries: int = 0  # transient-fault dispatches re-attempted
     degraded_nodes: int = 0  # pallas nodes demoted to eager after a kernel fault
     escalations: int = 0  # hash targets regrown after overflow
+    # host seconds in ``blaze.dispatch`` spans: each program dispatch up to
+    # its enqueue (a new executable's first, compiling call excluded) and
+    # each per-op dispatch attempt
+    dispatch_s: float = 0.0
+    feed_wait_s: float = 0.0  # host seconds run_stream waited for a block
 
     @property
     def hit_rate(self) -> float:
@@ -395,7 +401,8 @@ class BlazeSession:
                 faults.record("retried", e)
                 self.stats.retries += 1
                 if delay > 0:
-                    time.sleep(delay)
+                    with tracing.span("retry"):
+                        time.sleep(delay)
                 delay *= policy.multiplier
 
     def _degrade_op_node(self, node, e) -> None:
@@ -420,15 +427,20 @@ class BlazeSession:
         degrade just this node (not a whole program) and the returned
         ``MapReduceStats`` carries the recovery provenance
         (``degraded_engine``, ``retries``)."""
+
+        def attempt():
+            with tracing.span("dispatch", self.stats, "dispatch_s"):
+                return dispatch()
+
         policy = self.retry
         if policy is None:
-            return dispatch()
+            return attempt()
         t0 = time.monotonic()
         delay = policy.backoff_s
         tries = retries = 0
         while True:
             try:
-                out, stats = dispatch()
+                out, stats = attempt()
                 if retries or node.degraded_from is not None:
                     stats = dataclasses.replace(
                         stats, retries=retries,
@@ -454,7 +466,8 @@ class BlazeSession:
                 self.stats.retries += 1
                 retries += 1
                 if delay > 0:
-                    time.sleep(delay)
+                    with tracing.span("retry"):
+                        time.sleep(delay)
                 delay *= policy.multiplier
 
     def _maybe_escalate(self, out, stats, target, red, node, dispatch):
@@ -764,7 +777,9 @@ class BlazeSession:
             if cond is not None:
                 self.stats.host_syncs += 1
                 host_syncs += 1
-                if bool(cond(state)):
+                with tracing.span("sync"):
+                    done = bool(cond(state))
+                if done:
                     converged = True
                     break
         return state, LoopInfo(
@@ -813,7 +828,8 @@ class BlazeSession:
         counting it in ``stats.host_syncs`` so per-op loops and fused
         ``run_loop`` blocks are comparable."""
         self.stats.host_syncs += 1
-        return jax.device_get(x)
+        with tracing.span("sync"):
+            return jax.device_get(x)
 
     def foreach(self, v: C.DistVector, fn: Callable, env: Any = None) -> C.DistVector:
         """Session-scoped ``foreach`` (same executable-reuse contract via
@@ -860,6 +876,8 @@ class BlazeSession:
             "retries": self.stats.retries,
             "degraded_nodes": self.stats.degraded_nodes,
             "escalations": self.stats.escalations,
+            "dispatch_s": self.stats.dispatch_s,
+            "feed_wait_s": self.stats.feed_wait_s,
         }
 
     def clear_cache(self) -> None:

@@ -98,6 +98,40 @@ def test_program_tune_measures_once_across_run_loop_blocks():
     assert sess.stats.tune_measurements == first
 
 
+def _kmeans_program_run(sess, tune):
+    pts = np.random.RandomState(3).randint(-4, 5, size=(256, 4)).astype(
+        np.float32
+    )
+    step, state0 = _kmeans_step(C.distribute(pts, sess.mesh), 8, 4, "auto",
+                                "none")
+    prog = sess.program(step, mesh=sess.mesh, tune=tune)
+    state, _ = sess.run_loop(prog, state0(jnp.asarray(pts[:8])), max_iters=5)
+    return (np.asarray(state["centers"]),)
+
+
+def _wordcount_program_run(sess, tune):
+    hm = _hm(sess)
+    step, state0 = _wc_step(C.distribute(_tokens(), sess.mesh), hm, VOCAB,
+                            "auto")
+    prog = sess.program(step, mesh=sess.mesh, tune=tune)
+    prog(state0, 1)
+    return _counts(prog.hash_result(hm))
+
+
+@pytest.mark.parametrize("run", [_kmeans_program_run, _wordcount_program_run],
+                         ids=["kmeans", "wordcount"])
+def test_tuned_program_measures_once_and_equals_static_bit_for_bit(run):
+    static = run(BlazeSession(), False)
+    sess = BlazeSession()
+    first = run(sess, True)  # measures, then dispatches the winner
+    measured = sess.stats.tune_measurements
+    assert measured > 0
+    again = run(sess, True)  # a new program of the same plan: no measuring
+    assert sess.stats.tune_measurements == measured
+    for got in (first, again):
+        assert all(np.array_equal(a, b) for a, b in zip(static, got))
+
+
 def test_tuned_node_annotated_in_plan():
     sess = BlazeSession()
     pts = np.random.RandomState(1).randn(128, 4).astype(np.float32)
