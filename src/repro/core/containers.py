@@ -210,13 +210,34 @@ def hashmap_insert(
     Callers must pre-combine duplicates (``unique_combine``) — that is the
     eager-reduction invariant, so it is free by construction.
     """
+    return hashmap_insert_rounds(table, keys, vals, valid, reducer, max_probes)[0]
+
+
+def hashmap_insert_rounds(
+    table: HashTable,
+    keys: Array,
+    vals: Array,
+    valid: Array,
+    reducer: Reducer,
+    max_probes: int = 16,
+) -> tuple[HashTable, Array]:
+    """``hashmap_insert`` that also returns the probe rounds it ran.
+
+    The rounds run in a ``while_loop`` that stops once every valid pair is
+    placed, or after ``max_probes`` rounds; pairs still unplaced then are
+    counted into ``overflow``.  A round with no unplaced pair changes
+    nothing, so the table is the one ``max_probes`` full rounds would give.
+    The round count is an int32 scalar on the device.
+    """
     cap = table.capacity
     h = (hash32(keys) % jnp.uint32(cap)).astype(jnp.int32)
-    tkeys, tvals = table.keys, table.vals
-    active = valid
 
-    def round_body(r, state):
-        tkeys, tvals, active = state
+    def unfinished(state):
+        r, _, _, active = state
+        return (r < max_probes) & jnp.any(active)
+
+    def round_body(state):
+        r, tkeys, tvals, active = state
         slot = ((h + r) % cap).astype(jnp.int32)
         slot_key = jnp.take(tkeys, slot)
 
@@ -239,13 +260,14 @@ def hashmap_insert(
         tvals = tvals.at[jnp.where(deposit, slot, cap)].set(new_at_slot, mode="drop")
 
         active = active & ~deposit
-        return tkeys, tvals, active
+        return r + 1, tkeys, tvals, active
 
-    tkeys, tvals, active = jax.lax.fori_loop(
-        0, max_probes, round_body, (tkeys, tvals, active)
+    rounds, tkeys, tvals, active = jax.lax.while_loop(
+        unfinished, round_body,
+        (jnp.zeros((), jnp.int32), table.keys, table.vals, valid),
     )
     overflow = table.overflow + jnp.sum(active).astype(jnp.int32)
-    return HashTable(tkeys, tvals, overflow)
+    return HashTable(tkeys, tvals, overflow), rounds
 
 
 @jax.tree_util.register_dataclass

@@ -111,6 +111,9 @@ class MapReduceStats:
     # hash-aggregation kernel only: table geometry + probe depth.
     kernel_table_cap: int | None = None  # pre-shuffle combine table capacity
     kernel_probe_depth: int | None = None  # configured max probe rounds
+    # hash targets merged by hashmap_insert: the most probe rounds any
+    # shard's merge ran (device array until finalize; None elsewhere).
+    probe_rounds: Any = None
     # stable digest of this op's plan node (repro.core.plan) — identical for
     # the per-op and program spellings of the same op.
     plan_hash: str | None = None
@@ -151,6 +154,10 @@ class MapReduceStats:
             kernel_occupancy=occupancy,
             kernel_table_cap=self.kernel_table_cap,
             kernel_probe_depth=self.kernel_probe_depth,
+            probe_rounds=(
+                None if self.probe_rounds is None
+                else int(np.asarray(jax.device_get(self.probe_rounds)).max())
+            ),
             plan_hash=self.plan_hash,
             degraded_engine=self.degraded_engine,
             retries=self.retries,
@@ -877,20 +884,23 @@ def hash_shard_stage(
     shuffle, table merge) as a pure function of this shard's inputs:
 
         ``stage(env, table, local, coll)
-            -> (table', live_emitted, live_shipped, kernel_pairs)``
+            -> (table', live_emitted, live_shipped, kernel_pairs, rounds)``
 
     ``table`` is this shard's ``HashTable``; the returned table has the
-    shuffled pairs merged in and bucket drops added to ``overflow``.
+    shuffled pairs merged in and bucket drops added to ``overflow``;
+    ``rounds`` is the probe rounds ``hashmap_insert`` ran to merge them
+    (0 where the kernel merged).
 
     * ``engine="eager"`` combines locally with the sort-based
       ``unique_combine`` before the shuffle and merges received pairs with a
-      second ``unique_combine`` + ``hashmap_insert`` scatter loop.
+      second ``unique_combine`` + ``hashmap_insert`` scatter loop, which
+      stops once every received key is placed.
     * ``engine="pallas"`` lowers BOTH combines through the hash-aggregation
       kernel (``repro.kernels.hash_combine``): the pre-shuffle combine
       streams raw pairs into a fresh VMEM-resident table (duplicates fold
       in-kernel — no sort), and the post-shuffle merge streams received
       pairs straight into the target shard's table (``init=``), replacing
-      the ``unique_combine`` + 16-round ``hashmap_insert`` pair.
+      the ``unique_combine`` + ``hashmap_insert`` pair.
     * ``engine="naive"`` ships every raw pair and reduces at the
       destination only.
 
@@ -918,6 +928,7 @@ def hash_shard_stage(
         live_emitted = jnp.sum(valid).astype(jnp.int32)
         kernel_pairs = jnp.zeros((), jnp.int32)
         pre_drop = jnp.zeros((), jnp.int32)
+        rounds = jnp.zeros((), jnp.int32)
 
         if use_kernel:
             # Kernel local combine: raw pairs → fresh VMEM hash table.  The
@@ -1010,10 +1021,10 @@ def hash_shard_stage(
             merge_probes = max(
                 16, HK.choose_probe_depth(rkeys.shape[0], table.capacity)
             )
-            table = C.hashmap_insert(
+            table, rounds = C.hashmap_insert_rounds(
                 table, ukeys, uvals, uvalid, red, max_probes=merge_probes
             )
-        return table, live_emitted, live_shipped, kernel_pairs
+        return table, live_emitted, live_shipped, kernel_pairs, rounds
 
     return stage, kernel_meta
 
@@ -1048,7 +1059,7 @@ def _map_reduce_hash(
             coll = make_collectives(mesh, n_shards)
             local = _local_view(kind, source, operands)
             table = C.HashTable(tkeys[0], tvals[0], tovf[0])
-            table, live_emitted, live_shipped, kernel_pairs = stage(
+            table, live_emitted, live_shipped, kernel_pairs, rounds = stage(
                 env_, table, local, coll
             )
             return (
@@ -1058,6 +1069,7 @@ def _map_reduce_hash(
                 live_emitted[None],
                 live_shipped[None],
                 kernel_pairs[None],
+                rounds[None],
             )
 
         d = C.data_pspec(mesh)
@@ -1068,7 +1080,7 @@ def _map_reduce_hash(
                     shard_fn,
                     mesh=mesh,
                     in_specs=in_specs,
-                    out_specs=(d, d, d, d, d, d),
+                    out_specs=(d, d, d, d, d, d, d),
                     check_vma=False,
                 )
             ),
@@ -1080,7 +1092,7 @@ def _map_reduce_hash(
     faults.fault_point("dispatch")
     if engine == "pallas":
         faults.fault_point("kernel.hash")
-    nk, nv, novf, emitted, shipped, kernel_pairs = run_fn(
+    nk, nv, novf, emitted, shipped, kernel_pairs, rounds = run_fn(
         env, target.table.keys, target.table.vals, target.table.overflow, *operands
     )
     out = C.DistHashMap(C.HashTable(nk, nv, novf), reducer_name=red.name)
@@ -1111,6 +1123,7 @@ def _map_reduce_hash(
         kernel_pairs=kernel_pairs if kernel_meta else None,
         kernel_table_cap=kernel_meta.get("table_cap"),
         kernel_probe_depth=kernel_meta.get("probe_depth"),
+        probe_rounds=None if "merge_probe_depth" in kernel_meta else rounds,
         plan_hash=node.hash if node is not None else None,
     )
     return out, stats

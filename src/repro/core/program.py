@@ -842,7 +842,7 @@ class ProgramContext:
             shuffle_slack, self._n_shards, key_range=key_range,
             tuned=getattr(node, "tuned", None),
         )
-        table, _le, _ls, _kp = stage(env, table, local, self._coll)
+        table = stage(env, table, local, self._coll)[0]
         self._hash_tables[tkey] = table
         if self._mode == "discover" and node is not None:
             self._local_producers[id(table.keys)] = node.idx

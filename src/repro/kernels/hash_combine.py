@@ -5,8 +5,8 @@ small-fixed-key-range accumulator: key == index into a dense ``[K, V]`` VMEM
 tile.  Word-count-shaped workloads break its premise — the key space is open
 (any int32 word id) — and the hash path previously paid for it three times per
 MapReduce: a sort-based ``unique_combine`` before the shuffle, another one
-after it, and a 16-round scatter ``fori_loop`` (``hashmap_insert``) to merge
-into the target table.
+after it, and a scatter probe loop (``hashmap_insert``) to merge into the
+target table.
 
 ``hash_aggregate`` replaces all three with ONE streaming pass: an
 open-addressing (linear probing) hash table — ``keys [C]`` + ``vals [C, V]``

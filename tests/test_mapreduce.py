@@ -22,6 +22,7 @@ from repro.core.containers import (
     HashTable,
     hash32,
     hashmap_insert,
+    hashmap_insert_rounds,
     make_table,
     unique_combine,
 )
@@ -121,6 +122,147 @@ def test_hashmap_overflow_counted():
     t = hashmap_insert(t, keys, jnp.ones(32), jnp.ones(32, bool), red, max_probes=8)
     assert int(t.overflow) == 32 - int((np.asarray(t.keys) != EMPTY_KEY).sum())
     assert int(t.overflow) > 0
+
+
+def _fixed_round_insert(table, keys, vals, valid, reducer, max_probes):
+    """The probe loop as it was before its early exit: ``max_probes``
+    rounds of ``fori_loop``, whether or not any pair is left to place."""
+    cap = table.capacity
+    h = (hash32(keys) % jnp.uint32(cap)).astype(jnp.int32)
+
+    def round_body(r, state):
+        tkeys, tvals, active = state
+        slot = ((h + r) % cap).astype(jnp.int32)
+        slot_key = jnp.take(tkeys, slot)
+        want = active & (slot_key == EMPTY_KEY)
+        claim = jnp.full((cap,), EMPTY_KEY, jnp.int32)
+        claim = claim.at[jnp.where(want, slot, cap)].max(
+            jnp.where(want, keys, EMPTY_KEY), mode="drop"
+        )
+        tkeys = jnp.where(claim != EMPTY_KEY, claim, tkeys)
+        slot_key = jnp.take(tkeys, slot)
+        deposit = active & (slot_key == keys)
+        cur = jnp.take(tvals, slot, axis=0)
+        merged = reducer.combine(cur, vals)
+        db = deposit.reshape(deposit.shape + (1,) * (vals.ndim - 1))
+        new_at_slot = jnp.where(db, merged, cur)
+        tvals = tvals.at[jnp.where(deposit, slot, cap)].set(new_at_slot, mode="drop")
+        active = active & ~deposit
+        return tkeys, tvals, active
+
+    tkeys, tvals, active = jax.lax.fori_loop(
+        0, max_probes, round_body, (table.keys, table.vals, valid)
+    )
+    overflow = table.overflow + jnp.sum(active).astype(jnp.int32)
+    return HashTable(tkeys, tvals, overflow)
+
+
+# (load factor of the batch, max_probes, reducer, dtype, value shape,
+#  pre-filled table, some lanes invalid)
+_EARLY_EXIT_CASES = (
+    [(load, probes, "sum", "float32", (), False, False)
+     for load in (0.05, 0.25, 0.5, 0.9, 1.5) for probes in (1, 16, 64)]
+    + [(0.5, 16, red, dtype, shape, True, True)
+       for red, dtype in (("sum", "int32"), ("sum", "float32"),
+                          ("min", "float32"), ("max", "int32"))
+       for shape in ((), (3,))]
+    + [(0.9, 64, "min", "int32", (3,), True, False),
+       (1.5, 64, "max", "float32", (3,), False, True),
+       (0.25, 1, "sum", "int32", (), True, True)]
+)
+
+
+@pytest.mark.parametrize(
+    "load,max_probes,red_name,dtype,val_shape,prefill,masked", _EARLY_EXIT_CASES
+)
+def test_hashmap_insert_early_exit_matches_fixed_rounds(
+    load, max_probes, red_name, dtype, val_shape, prefill, masked
+):
+    """Stopping once every pair is placed gives, bit for bit, the keys,
+    values and overflow of running all ``max_probes`` rounds."""
+    cap = 256
+    red = get_reducer(red_name)
+    rng = np.random.RandomState(int(load * 100) + max_probes)
+
+    n, n_pre = int(load * cap), cap // 4
+    pool = rng.choice(1 << 20, n + n_pre, replace=False).astype(np.int32)
+
+    def vals_for(k):
+        return jnp.asarray(rng.randint(-50, 50, (len(k),) + val_shape), dtype)
+
+    table = make_table(cap, val_shape, jnp.dtype(dtype), red)
+    keys = pool[:n]
+    if prefill:  # a third of the batch merges into keys already resident
+        pkeys = np.concatenate([keys[: n // 3], pool[n:]])[:n_pre]
+        table = _fixed_round_insert(
+            table, jnp.asarray(pkeys), vals_for(pkeys), jnp.ones(n_pre, bool),
+            red, 64,
+        )
+    keys, vals = jnp.asarray(keys), vals_for(keys)
+    valid = jnp.asarray(rng.rand(n) > 0.3) if masked else jnp.ones(n, bool)
+
+    got = jax.jit(
+        lambda t, k, v, m: hashmap_insert(t, k, v, m, red, max_probes=max_probes)
+    )(table, keys, vals, valid)
+    want = jax.jit(
+        lambda t, k, v, m: _fixed_round_insert(t, k, v, m, red, max_probes)
+    )(table, keys, vals, valid)
+    for g, w in zip((got.keys, got.vals, got.overflow),
+                    (want.keys, want.vals, want.overflow)):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.dtype == w.dtype and g.shape == w.shape
+        assert g.tobytes() == w.tobytes()
+    if load > 1:
+        assert int(got.overflow) > 0
+
+
+def test_probe_rounds_empty_and_overflowing_batches():
+    red = get_reducer("sum")
+    keys = jnp.asarray(np.arange(32), jnp.int32)
+    ones = jnp.ones(32, jnp.float32)
+    _, rounds = hashmap_insert_rounds(
+        make_table(64, (), jnp.float32, red), keys, ones,
+        jnp.zeros(32, bool), red, max_probes=16,
+    )
+    assert int(rounds) == 0
+    t, rounds = hashmap_insert_rounds(
+        make_table(8, (), jnp.float32, red), keys, ones,
+        jnp.ones(32, bool), red, max_probes=8,
+    )
+    assert int(t.overflow) == 24 and int(rounds) == 8
+
+
+def test_probe_rounds_in_map_reduce_stats():
+    """A word-count-shaped merge: 131,072 received lanes into 131,072 slots
+    configure 64 probe rounds, yet keys from a 32,768-word vocabulary (load
+    at most 0.25) are all placed within a dozen."""
+    words = np.random.RandomState(7).randint(0, 32768, 131072).astype(np.int32)
+
+    def m(i, w, emit):
+        emit(w, 1)
+
+    hm = make_dist_hashmap(data_mesh(), 131072, (), jnp.int32, "sum")
+    out, stats = map_reduce(
+        distribute(words), m, "sum", hm, engine="eager", return_stats=True
+    )
+    assert isinstance(stats.probe_rounds, jax.Array)  # no host sync yet
+    stats = stats.finalize()
+    assert isinstance(stats.probe_rounds, int)
+    assert 0 < stats.probe_rounds <= 12
+    assert stats.overflow == 0
+    ids, counts = np.unique(words, return_counts=True)
+    assert {int(k): int(v) for k, v in out.to_dict().items()} == dict(
+        zip(ids.tolist(), counts.tolist())
+    )
+
+    # 40 distinct keys into 8 slots: the merge runs all of its 16 rounds.
+    small = make_dist_hashmap(data_mesh(), 8, (), jnp.int32, "sum")
+    _, stats = map_reduce(
+        distribute(np.arange(40, dtype=np.int32)), m, "sum", small,
+        engine="eager", return_stats=True,
+    )
+    stats = stats.finalize()
+    assert stats.probe_rounds == 16 and stats.overflow == 32
 
 
 # -- bucketing ----------------------------------------------------------------
