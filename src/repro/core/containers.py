@@ -126,6 +126,12 @@ def unique_combine(
     (folding garbage values into their run), and a genuine ``EMPTY_KEY``
     key is now emitted with ``valid == True`` — ``valid``, not the key
     value, is the liveness contract for downstream consumers.
+
+    The output keeps the input's length, ``n`` lanes, most of them empty
+    when keys repeat.  Where the distinct keys have a static bound (a hash
+    target's ``key_range``), ``hash_shard_stage`` passes the output through
+    ``compact_live`` to that many lanes and counts any excess into the
+    table's ``overflow``.
     """
     n = keys.shape[0]
     if n == 0:
@@ -158,6 +164,30 @@ def unique_combine(
     vb = valid.reshape(valid.shape + (1,) * (svals.ndim - 1))
     out_vals = jnp.where(vb, scanned, ident)
     return out_keys, out_vals, valid
+
+
+def compact_live(
+    keys: Array, vals: Array, valid: Array, lanes: int, ident
+) -> tuple[Array, Array, Array, Array]:
+    """Move the live pairs, in order, to the front of a ``lanes``-long buffer.
+
+    Returns ``(keys, vals, valid, excess)``: the first ``lanes`` live pairs
+    keep their relative order, free lanes hold ``EMPTY_KEY`` and ``ident``,
+    and ``excess`` (int32 scalar) counts the live pairs that did not fit.
+    One scatter of each lane's index to its rank among live lanes, then
+    gathers of only ``lanes`` lanes.
+    """
+    n = keys.shape[0]
+    rank = jnp.cumsum(valid, dtype=jnp.int32) - 1
+    src = jnp.full((lanes,), n, jnp.int32).at[jnp.where(valid, rank, lanes)].set(
+        jnp.arange(n, dtype=jnp.int32), mode="drop"
+    )
+    out_valid = src < n
+    out_keys = jnp.where(out_valid, jnp.take(keys, src, mode="clip"), EMPTY_KEY)
+    vb = out_valid.reshape(out_valid.shape + (1,) * (vals.ndim - 1))
+    out_vals = jnp.where(vb, jnp.take(vals, src, axis=0, mode="clip"), ident)
+    excess = jnp.maximum(rank[-1] + 1 - lanes, 0)
+    return out_keys, out_vals, out_valid, excess
 
 
 # ---------------------------------------------------------------------------

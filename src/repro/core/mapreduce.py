@@ -114,6 +114,9 @@ class MapReduceStats:
     # hash targets merged by hashmap_insert: the most probe rounds any
     # shard's merge ran (device array until finalize; None elsewhere).
     probe_rounds: Any = None
+    # hash targets: lanes each shard buckets after its local combine
+    # (static; pairs_shipped / (shuffle_lanes * n_shards) is their occupancy).
+    shuffle_lanes: int | None = None
     # stable digest of this op's plan node (repro.core.plan) — identical for
     # the per-op and program spellings of the same op.
     plan_hash: str | None = None
@@ -158,6 +161,7 @@ class MapReduceStats:
                 None if self.probe_rounds is None
                 else int(np.asarray(jax.device_get(self.probe_rounds)).max())
             ),
+            shuffle_lanes=self.shuffle_lanes,
             plan_hash=self.plan_hash,
             degraded_engine=self.degraded_engine,
             retries=self.retries,
@@ -906,18 +910,26 @@ def hash_shard_stage(
 
     ``key_range`` (keys known to lie in ``[0, key_range)``) narrows the
     bucket-key dtype on the wire and sizes the kernel's combine table by the
-    distinct-key bound instead of the stream length.
+    distinct-key bound instead of the stream length.  On the eager path it
+    also bounds the combined pairs: ``unique_combine``'s ``n_emit`` lanes
+    are compacted (``compact_live``) to ``min(n_emit, key_range)`` before
+    bucketing, so the bucketing, the shuffle and the merge run on those
+    lanes; live pairs beyond them (a broken promise) are counted into
+    ``overflow`` like bucket drops.  The merge keeps the probe depth of the
+    uncompacted buckets, so its table and rounds are those it would give
+    without the compaction.
 
     Standalone ``map_reduce`` wraps one stage in ``shard_map`` + ``jit``
     (``_map_reduce_hash``); ``repro.core.program`` composes it into fused
     iteration bodies with the shard's table threaded through the loop carry.
-    Returns ``(stage, kernel_meta)`` — ``kernel_meta`` is filled at trace
-    time with the kernel launch geometry when the kernel runs.
+    Returns ``(stage, meta)`` — ``meta`` is filled at trace time with
+    ``shuffle_lanes`` (the lanes each shard buckets) and, when the kernel
+    runs, its launch geometry.
     """
     from repro.kernels import hash_combine as HK
 
     use_kernel = engine == "pallas" and red.pallas_hash is not None
-    kernel_meta: dict = {}
+    meta: dict = {}
 
     def stage(env_, table, local, coll):
         keys, vals, valid = _run_mapper(
@@ -959,18 +971,26 @@ def hash_shard_stage(
                 n_emit, cap, vflat.shape[1], red.name, vflat.dtype,
                 block_n=bn,
             )
-            kernel_meta.update(
+            meta.update(
                 block_n=bn, lanes=lanes * n_shards, table_cap=cap,
                 probe_depth=probes,
             )
-        elif engine == "eager":
+        ident = red.identity(vals.dtype)
+        bucket_cap = max(1, int(math.ceil(slack * n_emit / n_shards)))
+        # The merge's probe depth is sized from the lanes it would receive
+        # without the compaction below: compacting changes no table or round.
+        merge_lanes = n_shards * min(bucket_cap, keys.shape[0])
+        if engine == "eager":
             keys, vals, valid = C.unique_combine(keys, vals, valid, red)
+            if key_range is not None and key_range < n_emit:
+                keys, vals, valid, pre_drop = C.compact_live(
+                    keys, vals, valid, key_range, ident
+                )
         live_shipped = jnp.sum(valid).astype(jnp.int32)
 
         n_stream = keys.shape[0]
-        bucket_cap = max(1, int(math.ceil(slack * n_emit / n_shards)))
+        meta["shuffle_lanes"] = n_stream
         bucket_cap = min(bucket_cap, n_stream)
-        ident = red.identity(vals.dtype)
         bkeys, bvals, dropped = bucket_by_dest(
             keys, vals, valid, n_shards, bucket_cap, ident
         )
@@ -1013,20 +1033,20 @@ def hash_shard_stage(
             table = C.HashTable(
                 tk, tv.reshape(table.vals.shape).astype(val_dtype), ovf
             )
-            kernel_meta.setdefault("merge_probe_depth", merge_probes)
+            meta.setdefault("merge_probe_depth", merge_probes)
         else:
             ukeys, uvals, uvalid = C.unique_combine(rkeys, rvals, rvalid, red)
             # Same adaptive probe depth as the kernel merge: near-capacity
             # tables need more rounds to *find* the free slots that exist.
             merge_probes = max(
-                16, HK.choose_probe_depth(rkeys.shape[0], table.capacity)
+                16, HK.choose_probe_depth(merge_lanes, table.capacity)
             )
             table, rounds = C.hashmap_insert_rounds(
                 table, ukeys, uvals, uvalid, red, max_probes=merge_probes
             )
         return table, live_emitted, live_shipped, kernel_pairs, rounds
 
-    return stage, kernel_meta
+    return stage, meta
 
 
 def _map_reduce_hash(
@@ -1050,7 +1070,7 @@ def _map_reduce_hash(
 
     compiled_now = cache_key not in cache
     if compiled_now:
-        stage, kernel_meta = hash_shard_stage(
+        stage, meta = hash_shard_stage(
             kind, source, mapper, red, target.table.vals.dtype, engine,
             slack, n_shards, key_range=key_range, tuned=tuned,
         )
@@ -1084,10 +1104,10 @@ def _map_reduce_hash(
                     check_vma=False,
                 )
             ),
-            kernel_meta,
+            meta,
         )
 
-    run_fn, kernel_meta = cache[cache_key]
+    run_fn, meta = cache[cache_key]
     operands, _ = _source_operands(kind, source)
     faults.fault_point("dispatch")
     if engine == "pallas":
@@ -1118,12 +1138,13 @@ def _map_reduce_hash(
         overflow=novf,
         compiles=int(compiled_now),
         cache_hits=int(not compiled_now),
-        kernel_block_n=kernel_meta.get("block_n"),
-        kernel_lanes=kernel_meta.get("lanes"),
-        kernel_pairs=kernel_pairs if kernel_meta else None,
-        kernel_table_cap=kernel_meta.get("table_cap"),
-        kernel_probe_depth=kernel_meta.get("probe_depth"),
-        probe_rounds=None if "merge_probe_depth" in kernel_meta else rounds,
+        kernel_block_n=meta.get("block_n"),
+        kernel_lanes=meta.get("lanes"),
+        kernel_pairs=kernel_pairs if "lanes" in meta else None,
+        kernel_table_cap=meta.get("table_cap"),
+        kernel_probe_depth=meta.get("probe_depth"),
+        probe_rounds=None if "merge_probe_depth" in meta else rounds,
+        shuffle_lanes=meta["shuffle_lanes"],
         plan_hash=node.hash if node is not None else None,
     )
     return out, stats

@@ -4,6 +4,10 @@ Zipfian skew, all-pairs-collide-to-one-slot, duplicate-heavy batches, and
 table-near-capacity overflow — plus the wire-narrowing and stable-bucketing
 satellites and the fused program-mode wordcount acceptance counters."""
 import collections
+import json
+import os
+import subprocess
+import sys
 
 import jax.numpy as jnp
 import numpy as np
@@ -12,6 +16,7 @@ import pytest
 from repro.core import BlazeSession, distribute, make_dist_hashmap
 from repro.core import containers as C
 from repro.core.mapreduce import bucket_by_dest
+from repro.launch.simulate import simulated_env
 
 ENGINES = ("eager", "pallas", "naive")
 
@@ -295,3 +300,128 @@ def test_program_multipass_hash_then_dense():
     }
     assert got_hist == dict(hist_ref)
     assert prog.hash_slots == 1 and prog.stats.compiles == 1
+
+
+# -- lane compaction of the eager local combine --------------------------------
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# name: (host devices, key law, emitted pairs, key range, table capacity per
+# shard, reducer).  Every range is below the pairs a shard emits, so the
+# combine's output is compacted to it.
+_COMPACTION_CASES = {
+    "zipf": (1, "zipf", 8192, 2048, 8192, "sum"),
+    "uniform": (1, "uniform", 8192, 2048, 8192, "sum"),
+    "cell-block": (1, "zipf", 131072, 32768, 131072, "sum"),
+    "near-capacity": (1, "uniform", 8192, 1024, 1400, "min"),
+    "mesh-zipf": (8, "zipf", 32768, 1024, 1024, "sum"),
+    "mesh-near-capacity": (8, "uniform", 32768, 1024, 136, "max"),
+}
+
+
+def _int_mapper(i, row, emit):
+    emit(row[0], row[1])
+
+
+def _compaction_pair(name: str, seed: int = 0) -> dict:
+    """One eager ``map_reduce`` of the case's pairs with ``key_range`` and
+    one without, on the session's mesh: whether the tables match byte for
+    byte, each side's overflow, probe rounds and shuffle lanes, and whether
+    the answer is the dict oracle's (survivors exact, every distinct key
+    either in the table or counted in ``overflow``)."""
+    _, law, n, key_range, cap, reducer = _COMPACTION_CASES[name]
+    rng = np.random.RandomState(seed)
+    if law == "zipf":  # bounded Zipf, exponent 1.0, as the word-count cell
+        p = 1.0 / np.arange(1, key_range + 1)
+        keys = rng.choice(key_range, n, p=p / p.sum())
+    else:
+        keys = rng.randint(0, key_range, n)
+    vals = rng.randint(-1000, 1000, n)
+    rows = distribute(np.stack([keys, vals], 1).astype(np.int32), SESS.mesh)
+    sides = {}
+    for kr in (None, key_range):
+        hm = make_dist_hashmap(SESS.mesh, cap, (), jnp.int32, reducer)
+        out, st = SESS.map_reduce(
+            rows, _int_mapper, reducer, hm, engine="eager", key_range=kr,
+            return_stats=True,
+        )
+        st = st.finalize()
+        sides[kr] = (out, st)
+    (wide, wst), (narrow, nst) = sides[None], sides[key_range]
+    same = {
+        f: np.asarray(getattr(wide.table, f)).tobytes()
+        == np.asarray(getattr(narrow.table, f)).tobytes()
+        for f in ("keys", "vals", "overflow")
+    }
+    want = _dict_oracle(keys, vals, np.ones(n), reducer)
+    got = {int(k): int(v) for k, v in narrow.to_dict().items()}
+    return {
+        "same": same,
+        "overflow": [wst.overflow, nst.overflow],
+        "rounds": [wst.probe_rounds, nst.probe_rounds],
+        "lanes": [wst.shuffle_lanes, nst.shuffle_lanes],
+        "n_emit": -(-n // narrow.n_shards),
+        "exact": all(want[k] == v for k, v in got.items())
+        and len(got) + nst.overflow == len(want),
+    }
+
+
+@pytest.mark.parametrize("name", list(_COMPACTION_CASES))
+def test_key_range_compaction_matches_uncompacted(name):
+    """With ``key_range`` the eager combine's output is compacted to
+    ``min(n_emit, key_range)`` lanes before bucketing; the table (keys,
+    values, overflow) and the merge's probe rounds are the ones the
+    uncompacted path gives, bit for bit, and the answer is exact."""
+    devices, *_, key_range, _, _ = _COMPACTION_CASES[name]
+    if devices == 1:
+        res = _compaction_pair(name)
+    else:
+        env = simulated_env(devices)
+        env["PYTHONPATH"] = os.pathsep.join(
+            [os.path.join(_ROOT, "src"), _ROOT]
+        )
+        code = (
+            "import json, sys\n"
+            f"sys.path.insert(0, {os.path.join(_ROOT, 'tests')!r})\n"
+            "import test_hash_differential as t\n"
+            f"print(json.dumps(t._compaction_pair({name!r})))\n"
+        )
+        out = subprocess.run(
+            [sys.executable, "-c", code], capture_output=True, text=True,
+            env=env, timeout=600,
+        )
+        assert out.returncode == 0, out.stderr[-3000:]
+        res = json.loads(out.stdout.strip().splitlines()[-1])
+    assert res["same"] == {"keys": True, "vals": True, "overflow": True}
+    assert res["overflow"][0] == res["overflow"][1]
+    assert res["rounds"][0] == res["rounds"][1] > 0
+    assert res["lanes"] == [res["n_emit"], key_range]
+    assert res["exact"]
+    if name.endswith("near-capacity"):
+        assert res["overflow"][1] > 0 or res["rounds"][1] > 16
+
+
+def test_broken_key_range_counts_excess_in_overflow():
+    """More distinct keys than ``key_range`` promised: the compacted buffer
+    keeps the first ``key_range`` of them (in key order), the rest are
+    counted in ``overflow``; none is lost silently."""
+    n, n_keys, key_range = 4096, 2000, 500
+    rng = np.random.RandomState(12)
+    keys = np.concatenate([np.arange(n_keys), rng.randint(0, n_keys, n - n_keys)])
+    vals = rng.randint(1, 9, n)
+    rows = distribute(np.stack([keys, vals], 1).astype(np.int32), SESS.mesh)
+    want = _dict_oracle(keys, vals, np.ones(n))
+    for kr, lanes in ((None, n), (key_range, key_range)):
+        hm = make_dist_hashmap(SESS.mesh, 4096, (), jnp.int32, "sum")
+        out, st = SESS.map_reduce(
+            rows, _int_mapper, "sum", hm, engine="eager", key_range=kr,
+            return_stats=True,
+        )
+        st = st.finalize()
+        assert st.shuffle_lanes == lanes
+        got = {int(k): int(v) for k, v in out.to_dict().items()}
+        assert all(want[k] == v for k, v in got.items())
+        assert len(got) + st.overflow == n_keys
+    assert st.overflow == n_keys - key_range
+    assert sorted(got) == list(range(key_range))
+    assert st.pairs_shipped == key_range

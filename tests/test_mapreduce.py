@@ -20,6 +20,7 @@ from repro.core import (
 )
 from repro.core.containers import (
     HashTable,
+    compact_live,
     hash32,
     hashmap_insert,
     hashmap_insert_rounds,
@@ -263,6 +264,31 @@ def test_probe_rounds_in_map_reduce_stats():
     )
     stats = stats.finalize()
     assert stats.probe_rounds == 16 and stats.overflow == 32
+
+
+
+@pytest.mark.parametrize("n,lanes,p_live", [
+    (64, 16, 0.1), (64, 16, 0.5), (64, 16, 1.0), (64, 16, 0.0), (8, 8, 0.7),
+])
+def test_compact_live_keeps_order_and_counts_excess(n, lanes, p_live):
+    """The live pairs move, in order, to the front of a ``lanes``-long
+    buffer (values of any shape with them); the rest of it is empty, and
+    live pairs beyond ``lanes`` are counted, not kept."""
+    rng = np.random.RandomState(n + lanes + int(10 * p_live))
+    keys = rng.randint(0, 1000, n).astype(np.int32)
+    vals = rng.randint(-9, 9, (n, 3)).astype(np.float32)
+    valid = rng.rand(n) < p_live
+    ck, cv, cvalid, excess = jax.jit(
+        lambda k, v, m: compact_live(k, v, m, lanes, -1.0)
+    )(keys, vals, valid)
+    live = np.flatnonzero(valid)
+    kept = live[:lanes]
+    assert int(excess) == len(live) - len(kept)
+    np.testing.assert_array_equal(np.asarray(cvalid), np.arange(lanes) < len(kept))
+    np.testing.assert_array_equal(np.asarray(ck)[: len(kept)], keys[kept])
+    np.testing.assert_array_equal(np.asarray(cv)[: len(kept)], vals[kept])
+    assert (np.asarray(ck)[len(kept):] == EMPTY_KEY).all()
+    assert (np.asarray(cv)[len(kept):] == -1.0).all()
 
 
 # -- bucketing ----------------------------------------------------------------
