@@ -1,49 +1,29 @@
-"""Cost-based planning: per-node cost model, kernel-config candidate grids,
-and the first-dispatch autotuning cache.
+"""The kernels' sizing grids and picks, and the engine cost model.
 
-Until PR 8 the planner made its one load-bearing choice — eager XLA reduce vs
-the Pallas VMEM-resident kernel — with a single static rule
-(``K <= PALLAS_AUTO_MAX_KEYS``), and the two kernel autotuners
-(``segment_reduce.choose_block_n``, ``hash_combine.choose_table_cap``)
-duplicated the VMEM-budget arithmetic while scoring candidates with analytic
-formulas that never saw a measurement.  This module closes ROADMAP open item
-2 in three layers:
+* **Sizing grids** (``segment_block_candidates``,
+  ``hash_table_candidates``): one implementation of the VMEM working-set
+  arithmetic (``segment_working_set``, ``hash_working_set``) for both
+  Pallas kernels, listing every launch geometry that fits ``VMEM_BUDGET``
+  with its working set.
+* **Picks** (``choose_block_n``, ``choose_table_cap``, ``hash_block_n``,
+  ``choose_probe_depth``): the geometry each kernel launches with, the
+  largest block on its grid clamped to the stream.  ``next_capacity``
+  walks the hash capacities one rung at a time for overflow escalation.
+* **Engine model** (``node_cost``, ``pick_engine``): how
+  ``engine="auto"`` chooses between eager XLA and the Pallas kernel from
+  the key count K.  Costs are in abstract accumulator-row units: the kernel
+  pays ~2 rows per key (accumulate + writeback), eager ~1 row per key plus
+  a fixed ``EAGER_FIXED_ROWS`` sort/lowering overhead, so the crossover is
+  ``K == PALLAS_AUTO_MAX_KEYS``.
 
-* **Candidate grids** (``segment_block_candidates`` /
-  ``hash_table_candidates``): ONE implementation of the VMEM working-set
-  arithmetic, exposing every config the greedy tuners consider together with
-  its working-set score.  The kernels' ``choose_*`` functions are now thin
-  argmax-style picks over these grids (bit-identical to the pre-PR-8 greedy
-  loops), and the measured autotuner times a small slice of the same grid
-  instead of re-deriving one.
-* **Calibrated fallback model** (``node_cost`` / ``pick_engine``): the
-  no-measurement engine policy.  Costs are in abstract *accumulator-row
-  units*: the kernel pays ~2 rows of VMEM traffic per key (accumulate +
-  writeback) while eager's segment-sort path pays ~1 row per key plus a
-  fixed ``EAGER_FIXED_ROWS`` lowering/sort overhead.  The crossover is
-  exactly ``K == PALLAS_AUTO_MAX_KEYS`` — the policy ``engine="auto"``
-  shipped with since PR 2 — so resolution stays deterministic and the PR 2
-  differential matrix keeps pinning it.
-* **Measured autotuning** (``TunedConfig`` / ``TuningCache``): with
-  ``tune=True`` the session times the candidate grid on the first dispatch
-  of a plan and caches the winner keyed by the node's plan hash; every later
-  dispatch — per-op, ``run_loop`` block, or BlazeServe query — reuses the
-  measured config.  The cache is JSON-persistable beside checkpoints
-  (``save``/``load``).
-
-Import discipline: this module imports ONLY jax/numpy/stdlib — never
-``repro.*`` — so the kernels (which sit *below* ``repro.core`` in the import
-order) can import it at module level without a cycle.
+Import discipline: this module imports only jax.numpy and the standard
+library, never ``repro.*``.  The kernels still import it lazily, inside the
+functions that need it (``hash_combine.py``, ``segment_reduce.py``):
+importing ``repro.core.cost`` runs ``repro/core/__init__``, which imports
+the kernels through ``reducers.py``, so a module-level import from a kernel
+would be a cycle.
 """
 from __future__ import annotations
-
-import dataclasses
-import json
-import os
-import tempfile
-import threading
-import warnings
-from typing import Iterator
 
 import jax.numpy as jnp
 
@@ -51,16 +31,12 @@ __all__ = [
     "EAGER_FIXED_ROWS",
     "PALLAS_AUTO_MAX_KEYS",
     "VMEM_BUDGET",
-    "TunedConfig",
-    "TuningCache",
     "acc_dtype",
     "choose_block_n",
     "choose_probe_depth",
     "choose_table_cap",
-    "dense_tuning_candidates",
     "hash_block_n",
     "hash_table_candidates",
-    "hash_tuning_candidates",
     "next_capacity",
     "node_cost",
     "pick_engine",
@@ -69,7 +45,7 @@ __all__ = [
     "use_matmul",
 ]
 
-# Default VMEM budget for both kernel autotuners (bytes).  Mosaic's scoped
+# Default VMEM budget for both kernels' sizing picks (bytes).  Mosaic's scoped
 # VMEM limit on a v5e core is 16 MiB (the limit its compiler reports when a
 # kernel overflows); the working-set models below count lane/sublane padding
 # as Mosaic lays tiles out, and the budget keeps a quarter of the limit free
@@ -106,7 +82,7 @@ def use_matmul(reducer: str, acc) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# Candidate grids + scores (the deduplicated tuner logic)
+# Candidate grids + scores (one sizing model for both kernels)
 # ---------------------------------------------------------------------------
 
 
@@ -152,7 +128,7 @@ def segment_block_candidates(
     n: int, num_segments: int, v: int, reducer: str = "sum",
     dtype=jnp.float32, vmem_budget: int = VMEM_BUDGET,
 ) -> list[tuple[int, int]]:
-    """Every ``block_n`` the dense-kernel tuner considers, with its score.
+    """Every ``block_n`` the dense kernel can launch with, with its score.
 
     Returns ``[(block_n, working_set_bytes), ...]`` in ascending block order:
     power-of-two blocks from one 128-lane tile up to ``MAX_BLOCK`` whose
@@ -230,7 +206,7 @@ def hash_table_candidates(
     distinct_hint: int | None = None,
     vmem_budget: int = VMEM_BUDGET,
 ) -> list[tuple[int, int, int, int]]:
-    """Every ``(cap, block_n)`` pair the hash-kernel tuner considers.
+    """Every ``(cap, block_n)`` pair the hash kernel can launch with.
 
     Returns ``[(table_cap, block_n, max_probes, working_set_bytes), ...]``:
     the capacity is fixed first (load factor ≤ 0.5 over the distinct-key
@@ -319,212 +295,3 @@ def pick_engine(k: int) -> str:
     if k <= 0:
         return "eager"
     return "pallas" if node_cost("pallas", k) <= node_cost("eager", k) else "eager"
-
-
-# ---------------------------------------------------------------------------
-# Measured autotuning: configs, candidate enumeration, cache
-# ---------------------------------------------------------------------------
-
-
-@dataclasses.dataclass(frozen=True)
-class TunedConfig:
-    """One execution config for a MapReduce node — a measurement candidate,
-    and (once timed) the cached winner.
-
-    ``wall_s`` and ``source`` are measurement *outcomes*, excluded from
-    equality/hash so a config's identity — and with it the executable-cache
-    key it participates in — depends only on what actually lowers.
-    """
-
-    engine: str  # "eager" | "pallas"
-    block_n: int | None = None  # dense/hash kernel block override
-    table_cap: int | None = None  # hash kernel: capacity override
-    probe_depth: int | None = None  # hash kernel: probe rounds override
-    source: str = dataclasses.field(default="fallback", compare=False)
-    wall_s: float | None = dataclasses.field(default=None, compare=False)
-
-    def describe(self) -> str:
-        parts = [self.engine]
-        if self.table_cap:
-            parts.append(f"cap={self.table_cap}")
-        if self.block_n:
-            parts.append(f"bn={self.block_n}")
-        if self.probe_depth:
-            parts.append(f"probes={self.probe_depth}")
-        return " ".join(parts)
-
-    def to_dict(self) -> dict:
-        return dataclasses.asdict(self)
-
-    @classmethod
-    def from_dict(cls, d: dict) -> "TunedConfig":
-        fields = {f.name for f in dataclasses.fields(cls)}
-        return cls(**{k: v for k, v in d.items() if k in fields})
-
-
-def dense_tuning_candidates(
-    k: int, v: int, reducer: str, dtype, *, vmem_budget: int = VMEM_BUDGET,
-) -> list[TunedConfig]:
-    """The measurement grid for a dense-target node: eager, the kernel at
-    its analytic default block, and the kernel one block step down/up the
-    shared candidate frontier.  Every candidate reduces with the same monoid
-    over the same pairs — results are bit-identical for exact inputs."""
-    cands = [TunedConfig(engine="eager")]
-    grid = [bn for bn, _ in segment_block_candidates(
-        1 << 30, k, v, reducer, dtype, vmem_budget
-    )]
-    default = grid[-1]
-    picks = [default]
-    if default // 2 in grid:
-        picks.append(default // 2)
-    if default // 4 in grid:
-        picks.append(default // 4)
-    cands += [TunedConfig(engine="pallas", block_n=bn) for bn in picks]
-    return cands
-
-
-def hash_tuning_candidates(
-    v: int, reducer: str, dtype, *, key_range: int | None,
-    vmem_budget: int = VMEM_BUDGET,
-) -> list[TunedConfig]:
-    """The measurement grid for a hash-target node.
-
-    With a ``key_range`` the distinct-key bound is known statically, so full
-    ``(cap, block_n, probes)`` triples off the shared grid are safe to pin
-    (capacity stays ≥ 2x the distinct bound — no overflow risk, results stay
-    bit-identical across candidates).  Without one, capacity must follow the
-    runtime stream length, so only the engine is tuned and the in-stage
-    analytic tuner keeps picking the kernel config.
-    """
-    cands = [TunedConfig(engine="eager")]
-    if key_range is None:
-        cands.append(TunedConfig(engine="pallas"))
-        return cands
-    grid = hash_table_candidates(
-        1 << 30, v, reducer, dtype, distinct_hint=key_range,
-        vmem_budget=vmem_budget,
-    )
-    seen: set[tuple] = set()
-    for cap, bn, probes, _ in (grid[-1], grid[len(grid) // 2], grid[0]):
-        if (cap, bn) in seen:
-            continue
-        seen.add((cap, bn))
-        cands.append(TunedConfig(
-            engine="pallas", block_n=bn, table_cap=cap, probe_depth=probes
-        ))
-    return cands
-
-
-class TuningCache:
-    """Measured winners keyed by node plan-hash (``MapReduceNode.tune_key``).
-
-    Thread-safe (BlazeServe prepares plans under concurrent submissions).
-    ``measurements`` counts candidate timings performed, ``hits``/``misses``
-    count lookups — the counters the measure-exactly-once tests pin.
-    """
-
-    def __init__(self) -> None:
-        self._entries: dict[str, TunedConfig] = {}
-        self._lock = threading.Lock()
-        self.measurements = 0
-        self.hits = 0
-        self.misses = 0
-
-    def get(self, key: str) -> TunedConfig | None:
-        with self._lock:
-            cfg = self._entries.get(key)
-            if cfg is None:
-                self.misses += 1
-            else:
-                self.hits += 1
-            return cfg
-
-    def peek(self, key: str) -> TunedConfig | None:
-        with self._lock:
-            return self._entries.get(key)
-
-    def put(self, key: str, cfg: TunedConfig) -> None:
-        with self._lock:
-            self._entries[key] = cfg
-
-    def record_measurements(self, n: int) -> None:
-        with self._lock:
-            self.measurements += n
-
-    def __contains__(self, key: str) -> bool:
-        with self._lock:
-            return key in self._entries
-
-    def __len__(self) -> int:
-        with self._lock:
-            return len(self._entries)
-
-    def items(self) -> Iterator[tuple[str, TunedConfig]]:
-        with self._lock:
-            return iter(sorted(self._entries.items()))
-
-    def snapshot(self) -> dict:
-        with self._lock:
-            return {
-                "entries": len(self._entries),
-                "measurements": self.measurements,
-                "hits": self.hits,
-                "misses": self.misses,
-                "configs": {
-                    k: cfg.to_dict()
-                    for k, cfg in sorted(self._entries.items())
-                },
-            }
-
-    # -- persistence (beside checkpoints) -----------------------------------
-
-    def save(self, path: str) -> None:
-        """Atomic JSON dump (tmp + rename, same discipline as checkpoints)."""
-        doc = {
-            "version": 1,
-            "entries": {
-                k: cfg.to_dict() for k, cfg in sorted(self._entries.items())
-            },
-        }
-        d = os.path.dirname(os.path.abspath(path)) or "."
-        fd, tmp = tempfile.mkstemp(dir=d, prefix=".tuning-")
-        try:
-            with os.fdopen(fd, "w") as f:
-                json.dump(doc, f, indent=1, sort_keys=True)
-                # fsync before the rename: os.replace orders the directory
-                # entry, not the data blocks — without the sync a crash can
-                # commit a truncated file under the final name.
-                f.flush()
-                os.fsync(f.fileno())
-            os.replace(tmp, path)
-        except BaseException:
-            if os.path.exists(tmp):
-                os.unlink(tmp)
-            raise
-
-    def load(self, path: str) -> int:
-        """Merge entries from ``path`` (loaded winners keep their recorded
-        ``source``/``wall_s``); returns how many were loaded.
-
-        A truncated, corrupt, or otherwise unreadable cache is a warning,
-        not a crash: tuning is an optimisation, so the session starts with
-        whatever loaded (usually nothing) and re-measures on demand.
-        """
-        try:
-            with open(path) as f:
-                doc = json.load(f)
-            entries = doc.get("entries", {})
-            items = [
-                (k, TunedConfig.from_dict(d)) for k, d in entries.items()
-            ]
-        except (OSError, ValueError, TypeError, UnicodeDecodeError) as e:
-            warnings.warn(
-                f"ignoring unreadable tuning cache {path!r}: {e}",
-                RuntimeWarning,
-                stacklevel=2,
-            )
-            return 0
-        with self._lock:
-            for k, cfg in items:
-                self._entries[k] = cfg
-        return len(items)

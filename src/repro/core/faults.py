@@ -19,7 +19,6 @@ The named points (see ``POINTS``) cover every layer that can fail:
 ``prefetch.read``   a block read inside the prefetch worker
                     (``data/pipeline.py``)
 ``checkpoint.write``a checkpoint write (``checkpoint/manager.py``)
-``tuning.measure``  one autotuner candidate measurement
 ==================  ====================================================
 
 Rules trigger on an exact hit number (``at=``), periodically (``every=``),
@@ -33,22 +32,20 @@ seed=42,fatal"``) or from the API (:func:`configure` / :func:`inject`).
 A fired rule raises :class:`TransientFault` (retryable) or
 :class:`FatalFault` (must propagate).  The registry also keeps the
 *recovery ledger*: every injected fault is eventually disposed exactly once
-(``retried`` / ``degraded`` / ``escalated`` / ``fatal`` / ``absorbed``) by
-whichever supervisor caught it, so the conservation law
+(``retried`` / ``degraded`` / ``escalated`` / ``fatal``) by whichever
+supervisor caught it, so the conservation law
 
-    ``injected_total == retried + degraded + escalated + fatal + absorbed``
+    ``injected_total == retried + degraded + escalated + fatal``
 
 is checkable from :func:`snapshot` after any run.  :func:`record` marks the
 fault instance itself, so a fault handed across threads (e.g. out of the
 prefetch worker) cannot be double-counted.
 
-When no rules are armed, :func:`fault_point` is a single attribute check —
-the fault-free overhead budget of ``benchmarks/bench9_faults.py`` depends
-on that fast path.
+When no rules are armed, :func:`fault_point` is a single attribute check,
+so the fault-free dispatch path pays nothing else for its fault points.
 
-Import discipline: stdlib only (like ``cost.py``), so kernels, the data
-pipeline, and the checkpoint manager can all import this module without
-cycles.
+Import discipline: stdlib only, so the data pipeline and the checkpoint
+manager can import this module without cycles.
 """
 from __future__ import annotations
 
@@ -88,11 +85,10 @@ POINTS = (
     "kernel.hash",
     "prefetch.read",
     "checkpoint.write",
-    "tuning.measure",
 )
 
 #: Terminal outcomes a supervisor can assign to an injected fault.
-DISPOSITIONS = ("retried", "degraded", "escalated", "fatal", "absorbed")
+DISPOSITIONS = ("retried", "degraded", "escalated", "fatal")
 
 ENV_VAR = "BLAZE_FAULTS"
 

@@ -487,7 +487,6 @@ def map_reduce(
     engine: str = "eager",
     wire: str = "none",
     env: Any = None,
-    shuffle_slack: float = 2.0,
     key_range: int | None = None,
     return_stats: bool = False,
     session=None,
@@ -506,7 +505,7 @@ def map_reduce(
     sess = session if session is not None else get_default_session()
     return sess.map_reduce(
         source, mapper, reducer, target, mesh=mesh, engine=engine, wire=wire,
-        env=env, shuffle_slack=shuffle_slack, key_range=key_range,
+        env=env, key_range=key_range,
         return_stats=return_stats,
     )
 
@@ -563,7 +562,7 @@ def dense_segment(red: Reducer, vals: Array, ids: Array, k: int) -> Array:
 
 def dense_shard_stage(
     kind, source, mapper, red, target, engine, wire, n_shards,
-    with_stats=True, feedback=False, collect=True, tuned=None, hier=False,
+    with_stats=True, feedback=False, collect=True, hier=False,
 ):
     """Build a pure, composable shard stage for a dense ``[K, ...]`` target.
 
@@ -602,11 +601,9 @@ def dense_shard_stage(
 
     Returns ``(stage, kernel_meta)``; ``kernel_meta`` is filled at trace time
     with the Pallas launch geometry (``block_n``, ``lanes``) when the kernel
-    runs.  ``tuned`` (a ``cost.TunedConfig``) pins the kernel's ``block_n``
-    instead of the analytic tuner — the measured-autotuning override.
+    runs.
     """
     K = target.shape[0]
-    tuned_bn = getattr(tuned, "block_n", None) if engine == "pallas" else None
     target_dtype = target.dtype
     kernel_meta: dict = {}
 
@@ -657,15 +654,14 @@ def dense_shard_stage(
                         dmask & (dkeys >= 0) & (dkeys < K), dkeys, -1
                     )
                     flat = dvals.reshape((dvals.shape[0], -1))
-                    seg = red.pallas_segment(ids, flat, K, block_n=tuned_bn)
+                    seg = red.pallas_segment(ids, flat, K)
                     seg = seg.reshape((K,) + dvals.shape[1:])
                     from repro.kernels.segment_reduce import (
                         segment_reduce_lanes,
                     )
 
                     bn, lanes = segment_reduce_lanes(
-                        flat.shape[0], K, flat.shape[1], red.name,
-                        flat.dtype, block_n=tuned_bn,
+                        flat.shape[0], K, flat.shape[1], red.name, flat.dtype
                     )
                     kernel_meta["block_n"] = bn
                     kernel_meta["lanes"] = lanes * n_shards
@@ -740,7 +736,7 @@ def reduce_edge_bytes(
 
 def _map_reduce_dense(
     kind, source, mapper, red, target, mesh, n_shards, engine, wire, env,
-    with_stats=True, cache=None, node=None, tuned=None, hier=False,
+    with_stats=True, cache=None, node=None, hier=False,
 ):
     """Dense [K, ...] target — the paper's small fixed key range fast path."""
     K = target.shape[0]
@@ -753,14 +749,12 @@ def _map_reduce_dense(
     # The executable cache key IS the plan node's identity-faithful cache
     # signature: everything that shapes the lowered plan, with the mapper and
     # reducer kept by object (two lambdas with one qualname stay distinct).
-    # A tuned kernel config bakes into the lowered kernel, so it is part of
-    # the identity (TunedConfig equality ignores measurement outcomes).
     cache_key = (
         "dense", mapper, red.name, red, engine, wire, mesh, kind, with_stats,
         _abstract(_source_operands(kind, source)[0]),
         getattr(source, "n", None) if kind in ("vector", "chunked") else
         (source.start, source.stop, source.step) if kind == "range" else None,
-        _abstract(target), _abstract(env), tuned,
+        _abstract(target), _abstract(env),
     ) + (("hier",) if hier else ())
     if node is not None:
         node.cache_sig = cache_key
@@ -769,7 +763,7 @@ def _map_reduce_dense(
     if compiled_now:
         stage, kernel_meta = dense_shard_stage(
             kind, source, mapper, red, target, engine, wire, n_shards,
-            with_stats=with_stats, tuned=tuned, hier=hier,
+            with_stats=with_stats, hier=hier,
         )
         d = C.data_pspec(mesh)
 
@@ -877,9 +871,14 @@ def _wire_key_dtype(key_range: int | None) -> jnp.dtype:
     return narrowest_int_dtype(key_range)
 
 
+# Each shard's bucket for one destination holds this many times its fair
+# share of the stream's lanes (``n_emit / n_shards``); pairs past it are
+# dropped into ``overflow``.
+BUCKET_SLACK = 2.0
+
+
 def hash_shard_stage(
-    kind, source, mapper, red, val_dtype, engine, slack, n_shards,
-    key_range=None, tuned=None,
+    kind, source, mapper, red, val_dtype, engine, n_shards, key_range=None,
 ):
     """Build the composable shard stage for a ``DistHashMap`` target.
 
@@ -947,19 +946,10 @@ def hash_shard_stage(
             # table's live rows *are* the locally-reduced pairs (at most one
             # per key), so the sort-based unique_combine disappears.
             vflat = vals.reshape((n_emit, -1))
-            if tuned is not None and tuned.table_cap:
-                # Measured override: the full (cap, block, probes) triple is
-                # pinned (only offered when key_range bounds the distinct
-                # keys, so the pinned capacity cannot overflow).
-                cap = tuned.table_cap
-                bn = tuned.block_n
-                probes = min(cap, tuned.probe_depth or
-                             HK.choose_probe_depth(n_emit, cap))
-            else:
-                cap, bn, probes = HK.choose_table_cap(
-                    n_emit, vflat.shape[1], red.name, vflat.dtype,
-                    distinct_hint=key_range,
-                )
+            cap, bn, probes = HK.choose_table_cap(
+                n_emit, vflat.shape[1], red.name, vflat.dtype,
+                distinct_hint=key_range,
+            )
             mkeys = jnp.where(valid, keys, HK.EMPTY_KEY)
             tk, tv, pre_drop = red.pallas_hash(
                 mkeys, vflat, cap, max_probes=probes, block_n=bn
@@ -976,7 +966,7 @@ def hash_shard_stage(
                 probe_depth=probes,
             )
         ident = red.identity(vals.dtype)
-        bucket_cap = max(1, int(math.ceil(slack * n_emit / n_shards)))
+        bucket_cap = max(1, int(math.ceil(BUCKET_SLACK * n_emit / n_shards)))
         # The merge's probe depth is sized from the lanes it would receive
         # without the compaction below: compacting changes no table or round.
         merge_lanes = n_shards * min(bucket_cap, keys.shape[0])
@@ -1050,20 +1040,19 @@ def hash_shard_stage(
 
 
 def _map_reduce_hash(
-    kind, source, mapper, red, target, mesh, n_shards, engine, slack, env,
-    key_range=None, cache=None, node=None, tuned=None,
+    kind, source, mapper, red, target, mesh, n_shards, engine, env,
+    key_range=None, cache=None, node=None,
 ):
     """DistHashMap target: local combine → hash-partition → all_to_all → merge."""
     cache = cache if cache is not None else {}
     nodes = C.n_nodes(mesh)
 
     cache_key = (
-        "hash", mapper, red.name, red, engine, slack, mesh, kind, key_range,
+        "hash", mapper, red.name, red, engine, mesh, kind, key_range,
         _abstract(_source_operands(kind, source)[0]),
         getattr(source, "n", None) if kind in ("vector", "chunked") else
         (source.start, source.stop, source.step) if kind == "range" else None,
         _abstract((target.table.keys, target.table.vals)), _abstract(env),
-        tuned,
     )
     if node is not None:
         node.cache_sig = cache_key
@@ -1072,7 +1061,7 @@ def _map_reduce_hash(
     if compiled_now:
         stage, meta = hash_shard_stage(
             kind, source, mapper, red, target.table.vals.dtype, engine,
-            slack, n_shards, key_range=key_range, tuned=tuned,
+            n_shards, key_range=key_range,
         )
 
         def shard_fn(env_, tkeys, tvals, tovf, *operands):

@@ -57,7 +57,7 @@ import numpy as np
 
 from repro.core import containers as C
 from repro.core import cost as cost_mod
-from repro.core.cost import PALLAS_AUTO_MAX_KEYS, TunedConfig, TuningCache
+from repro.core.cost import PALLAS_AUTO_MAX_KEYS
 from repro.core.reducers import Reducer
 
 __all__ = [
@@ -72,7 +72,6 @@ __all__ = [
     "SourceInfo",
     "abstract_sig",
     "apply_hierarchical",
-    "apply_tuned",
     "build_mapreduce_node",
     "hier_collective_desc",
     "node_key_count",
@@ -85,7 +84,7 @@ ENGINES = ("eager", "pallas", "naive", "auto")
 # The optimizer passes a Program runs by default, in order.  resolve-engines
 # is not optional (a node without a resolved engine cannot lower); the other
 # three can be switched off per program (``session.program(..., passes=())``)
-# — which is how benchmarks measure the before/after of collective batching.
+# — the unoptimized reference the tests compare optimized plans against.
 DEFAULT_PASSES = ("cse", "batch-collectives", "prune-dead-sources")
 
 # PALLAS_AUTO_MAX_KEYS now lives in repro.core.cost as the fallback cost
@@ -228,15 +227,12 @@ class MapReduceNode:
     dead: bool = False  # result provably unused -> op pruned
     collective: str = ""  # what carries this op's shuffle
     cache_sig: tuple | None = None  # identity-faithful executable cache key
-    # -- cost-model / autotuning annotations (NOT part of stable_desc: the
-    # tuning cache is keyed by the hash of the un-tuned node, so applying a
-    # cached winner must not move the key it was cached under) --------------
     cost_estimate: float | None = None  # model units for the resolved engine
-    tune_key: str = ""  # node hash at resolve time, before any tuned override
-    tuned: TunedConfig | None = None  # the applied winner (measured or loaded)
-    # -- fault-supervision provenance: the engine a kernel fault degraded
-    # this node FROM (None = never degraded).  Like tuned, not part of
-    # stable_desc — but degradation rewrites ``engine``, which is.
+    # -- fault supervision: ``resolved_hash`` is the node's hash as built,
+    # before any degradation rewrites ``engine`` (and so ``hash``); it keys
+    # the session's degraded set.  ``degraded_from`` is the engine a kernel
+    # fault degraded this node FROM (None = never degraded).
+    resolved_hash: str = ""
     degraded_from: str | None = None
     # -- hierarchical-collectives pass: True when the node's collective was
     # rewritten to the two-hop (intra-node full precision, inter-node wire)
@@ -323,11 +319,6 @@ class Plan:
     pruned_sources: int = 0
     residual_specs: list[tuple] = dataclasses.field(default_factory=list)
     hash_targets: dict = dataclasses.field(default_factory=dict)
-    # node idx -> (target_kind, k, v, reducer_name, dtype_str, key_range,
-    # has_kernel): the candidate-grid parameters the program autotuner needs
-    # to rebuild measurement variants without re-tracing.  Not part of the
-    # plan hash — it describes the same ops the hashed descs already cover.
-    tune_info: dict = dataclasses.field(default_factory=dict)
 
     @property
     def hash(self) -> str:
@@ -379,16 +370,8 @@ class Plan:
                         f"degraded {n.degraded_from!r} -> {n.engine!r} "
                         "(kernel fault)"
                     )
-                elif n.engine_requested != n.engine and n.tuned is None:
+                elif n.engine_requested != n.engine:
                     flags.append(f"requested {n.engine_requested!r}")
-                if n.tuned is not None:
-                    cfg = n.tuned
-                    wall = (
-                        f" {cfg.wall_s * 1e3:.2f}ms"
-                        if cfg.wall_s is not None
-                        else ""
-                    )
-                    flags.append(f"tuned {cfg.source}: {cfg.describe()}{wall}")
                 mapper_name = _fn_name(n.mapper).rsplit(".", 1)[-1]
                 body = (
                     f"map_reduce {n.reducer:<4} fn={mapper_name} "
@@ -476,21 +459,6 @@ def target_desc_of(target) -> tuple[str, str]:
     return "dense", f"dense {_dtype_name(t.dtype)}[{'x'.join(map(str, t.shape))}]"
 
 
-def apply_tuned(node: MapReduceNode, red: Reducer, cfg: TunedConfig) -> None:
-    """Apply a tuning-cache winner to a freshly built node: override the
-    resolved engine (when the reducer actually carries the kernel the config
-    asks for) and attach the kernel config for the stage builders.  The
-    override is applied *after* ``tune_key`` was captured, so the node's
-    cache identity in the tuning cache is unchanged."""
-    kernel = (
-        red.pallas_hash if node.target_kind == "hash" else red.pallas_segment
-    )
-    if cfg.engine == "pallas" and kernel is None:
-        return  # custom reducer: the config cannot lower; keep the fallback
-    node.engine = cfg.engine
-    node.tuned = cfg
-
-
 def hier_collective_desc(reducer_name: str, wire: str) -> str:
     """EXPLAIN rendering of a hierarchical collective, e.g.
     ``psum[node×data, hier, wire=int8@inter]``: the intra-node hop always
@@ -531,17 +499,15 @@ def degrade_node(node: MapReduceNode) -> None:
     """Degrade a kernel-faulted node to the always-available eager engine.
 
     Records where the node came FROM (rendered by ``explain`` and surfaced
-    as ``MapReduceStats.degraded_engine``) and drops any tuned kernel config
-    — a pinned Pallas geometry cannot lower the eager plan.  The rewritten
-    ``engine`` moves ``node.hash``/``cache_sig`` so the degraded executable
-    caches beside, never over, the faulted one; ``tune_key`` was captured
-    before any override and stays put.
+    as ``MapReduceStats.degraded_engine``).  The rewritten ``engine`` moves
+    ``node.hash``/``cache_sig`` so the degraded executable caches beside,
+    never over, the faulted one; ``resolved_hash`` was captured before and
+    stays put.
     """
     if node.engine == "eager":
         return
     node.degraded_from = node.engine
     node.engine = "eager"
-    node.tuned = None
 
 
 def build_mapreduce_node(
@@ -556,7 +522,6 @@ def build_mapreduce_node(
     wire: str,
     key_range: int | None,
     env: Any,
-    tuning: TuningCache | None = None,
     degraded: set | None = None,
     n_nodes: int = 1,
     hierarchical: bool = True,
@@ -566,16 +531,15 @@ def build_mapreduce_node(
     This is THE node constructor: ``BlazeSession.map_reduce`` builds its
     single-node plan through it and ``ProgramContext`` builds every program
     node through it, which is why the two paths produce identical node
-    hashes for the same op.  When a ``tuning`` cache is passed, a cached
-    measured winner for this node (keyed by its un-tuned hash) is applied
-    before the node is returned — the resolve-engines pass consulting the
-    measured cost model instead of the analytic fallback.
+    hashes for the same op.  A node whose ``resolved_hash`` is in
+    ``degraded`` (the session's kernel-faulted nodes) is degraded to eager
+    before it is returned.
 
     On multi-node meshes (``n_nodes > 1``) the ``hierarchical-collectives``
     pass runs here too — per node, like resolve-engines — unless the caller
-    opts out (``hierarchical=False``, the flat-topology A/B baseline).  It
-    runs BEFORE ``tune_key`` is captured: a hierarchical node is a
-    different plan, so it must not inherit flat-topology tuning winners.
+    opts out (``hierarchical=False``, the flat-topology reference).  It
+    runs BEFORE ``resolved_hash`` is captured: a hierarchical node is a
+    different plan from its flat twin.
     """
     target_kind, tdesc = target_desc_of(target)
     if target_kind == "hash":
@@ -623,15 +587,11 @@ def build_mapreduce_node(
         node.cost_estimate = cost_mod.node_cost(
             resolved, node_key_count(target)
         )
-    node.tune_key = node.hash  # identity BEFORE any tuned override
-    if tuning is not None:
-        cfg = tuning.get(node.tune_key)
-        if cfg is not None:
-            apply_tuned(node, red, cfg)
+    node.resolved_hash = node.hash
     # A node the session supervisor already degraded stays degraded: the
     # rebuilt node resolves straight to eager and hits the executable the
     # recovery dispatch compiled (the no-cache-poisoning contract).
-    if degraded and node.tune_key in degraded:
+    if degraded and node.resolved_hash in degraded:
         degrade_node(node)
     return node
 

@@ -70,8 +70,6 @@ leaving the executable).
 from __future__ import annotations
 
 import dataclasses
-import math
-import time
 from typing import Any, Callable
 
 import jax
@@ -364,23 +362,16 @@ class ProgramContext:
     def __init__(
         self, n_shards: int, mode: str, coll=None, operands=None,
         residuals=None, hash_tables=None, plan: Plan | None = None,
-        passes: tuple = DEFAULT_PASSES, tuning=None, overrides=None,
+        passes: tuple = DEFAULT_PASSES,
         degraded=None, n_nodes: int = 1, hierarchical: bool = True,
     ):
         self._n_shards = n_shards
         self._n_nodes = n_nodes
         self._hierarchical = hierarchical
         self._mode = mode  # "discover" | "execute"
-        # discover-mode autotuning hooks: ``tuning`` is the session's
-        # TuningCache (cached winners apply to every node built), and
-        # ``overrides`` maps tune_key -> candidate TunedConfig for the
-        # throwaway measurement variants Program._maybe_tune builds.
-        # ``degraded`` is the session's set of kernel-faulted tune_keys:
-        # nodes matching it resolve straight to eager on (re)discovery.
-        self._tuning = tuning
-        self._overrides = overrides or {}
+        # ``degraded`` is the session's set of kernel-faulted nodes (by
+        # ``resolved_hash``): they resolve straight to eager on discovery.
         self._degraded = degraded
-        self._tune_info: dict[int, tuple] = {}  # idx -> candidate-grid params
         inner = (
             coll if coll is not None
             else _mr.AbstractCollectives(n_shards, n_nodes=n_nodes)
@@ -647,7 +638,7 @@ class ProgramContext:
     def map_reduce(
         self, source, mapper: Callable, reducer, target, *,
         engine: str = "eager", wire: str = "none", env: Any = None,
-        shuffle_slack: float = 2.0, key_range: int | None = None,
+        key_range: int | None = None,
     ):
         """One MapReduce op, fused into the surrounding program.
 
@@ -675,7 +666,7 @@ class ProgramContext:
         if isinstance(target, C.DistHashMap):
             return self._map_reduce_hash(
                 source, mapper, red, target, engine=engine, env=env,
-                shuffle_slack=shuffle_slack, key_range=key_range,
+                key_range=key_range,
             )
         target = jnp.asarray(target)
         if self._mode == "execute" and self._plan is not None:
@@ -697,20 +688,12 @@ class ProgramContext:
                 idx=self._call_i, kind=kind, src=src_desc,
                 source_key=source_key, mapper=mapper, red=red, target=target,
                 engine=engine, wire=wire, key_range=key_range, env=env,
-                tuning=self._tuning, degraded=self._degraded,
+                degraded=self._degraded,
                 n_nodes=self._n_nodes, hierarchical=self._hierarchical,
             )
-            ov = self._overrides.get(node.tune_key)
-            if ov is not None:
-                plan_mod.apply_tuned(node, red, ov)
             self._call_i += 1
             self._nodes.append(node)
             self._meta[node.idx] = (red, target)
-            v = math.prod(target.shape[1:]) if target.ndim > 1 else 1
-            self._tune_info[node.idx] = (
-                "dense", target.shape[0] if target.ndim else 0, v, red.name,
-                str(target.dtype), None, red.pallas_segment is not None,
-            )
             if self._cse and not (
                 wire == "int8" and red.name == "sum"
             ):
@@ -756,8 +739,7 @@ class ProgramContext:
         stage, _ = _mr.dense_shard_stage(
             kind, src_static, mapper, red, target, resolved, wire,
             self._n_shards, with_stats=False, feedback=feedback,
-            collect=not deferrable, tuned=getattr(node, "tuned", None),
-            hier=node.hier,
+            collect=not deferrable, hier=node.hier,
         )
         residual = None
         if feedback:
@@ -780,8 +762,7 @@ class ProgramContext:
         return self._results[node.idx]
 
     def _map_reduce_hash(
-        self, source, mapper, red, target, *, engine, env, shuffle_slack,
-        key_range,
+        self, source, mapper, red, target, *, engine, env, key_range,
     ):
         """Hash-target op inside a program: per-shard table state.
 
@@ -799,20 +780,11 @@ class ProgramContext:
                 idx=self._call_i, kind=kind, src=src_desc,
                 source_key=source_key, mapper=mapper, red=red, target=target,
                 engine=engine, wire="none", key_range=key_range, env=env,
-                tuning=self._tuning, degraded=self._degraded,
+                degraded=self._degraded,
                 n_nodes=self._n_nodes, hierarchical=self._hierarchical,
             )
-            ov = self._overrides.get(node.tune_key)
-            if ov is not None:
-                plan_mod.apply_tuned(node, red, ov)
             self._call_i += 1
             self._nodes.append(node)
-            vals = target.table.vals
-            v = math.prod(vals.shape[2:]) if vals.ndim > 2 else 1
-            self._tune_info[node.idx] = (
-                "hash", 0, v, red.name, str(vals.dtype), key_range,
-                red.pallas_hash is not None,
-            )
         else:
             _, node = self._next_node(MapReduceNode)
         resolved = node.engine if node is not None else plan_mod.resolve_engine(
@@ -839,8 +811,7 @@ class ProgramContext:
         table = self._hash_tables[tkey]
         stage, _meta = _mr.hash_shard_stage(
             kind, src_static, mapper, red, target.table.vals.dtype, resolved,
-            shuffle_slack, self._n_shards, key_range=key_range,
-            tuned=getattr(node, "tuned", None),
+            self._n_shards, key_range=key_range,
         )
         table = stage(env, table, local, self._coll)[0]
         self._hash_tables[tkey] = table
@@ -984,7 +955,6 @@ class ProgramContext:
             pruned_sources=sum(1 for s in sources if s.pruned),
             residual_specs=residual_specs,
             hash_targets=dict(self._hash_targets),
-            tune_info=dict(self._tune_info),
         )
 
 
@@ -1022,32 +992,23 @@ class Program:
     ``program.plan`` (after :meth:`build` or the first dispatch) is the
     optimized :class:`repro.core.plan.Plan`; ``session.explain(program)``
     renders it.  ``passes=()`` disables the optimizer (CSE, collective
-    batching, dead-source pruning) for apples-to-apples comparisons —
-    ``benchmarks/paper_benchmarks.py::bench5_plan_batching`` uses exactly
-    that to report collectives-per-iteration before/after.
+    batching, dead-source pruning): the unoptimized plan the tests compare
+    the optimized one against (``tests/test_plan.py``).
     """
 
     def __init__(
         self, session, step_fn: Callable, *, mesh: Mesh | None = None,
-        passes: tuple | None = None, tune: bool = False,
-        overrides: dict | None = None, hierarchical: bool = True,
+        passes: tuple | None = None, hierarchical: bool = True,
     ):
         self._session = session
         self._step_fn = step_fn
         self._mesh = mesh if mesh is not None else session.mesh
         self._n_shards = C.shard_count(self._mesh)
         # ``hierarchical=False`` keeps collectives flat even on a multi-node
-        # mesh — the A/B baseline the scaling bench compares against.
+        # mesh — the reference the hierarchical plan is tested against.
         self._hierarchical = bool(hierarchical)
         self._n_nodes = C.n_nodes(self._mesh) if self._hierarchical else 1
         self._passes = DEFAULT_PASSES if passes is None else tuple(passes)
-        # ``tune``: on first build per state signature, measure the candidate
-        # grid for every tunable op (see _maybe_tune) and cache winners in
-        # the session's TuningCache.  ``overrides`` pins tune_key -> config
-        # for the throwaway measurement variants — such a variant never
-        # recursively tunes.
-        self._tune = bool(tune)
-        self._overrides = overrides
         self._cache: dict = {}  # state signature -> (jitted fused fn, operands)
         self._plans: dict = {}  # state signature -> optimized Plan
         # state signature -> live per-shard error-feedback residuals, carried
@@ -1075,7 +1036,6 @@ class Program:
     def _discover(self, state) -> Plan:
         ctx = ProgramContext(
             self._n_shards, "discover", passes=self._passes,
-            tuning=self._session.tuning, overrides=self._overrides,
             degraded=getattr(self._session, "_degraded", None),
             n_nodes=self._n_nodes, hierarchical=self._hierarchical,
         )
@@ -1102,97 +1062,6 @@ class Program:
                 )
         return ctx.build_plan(_state_desc(state), self._passes)
 
-    def _maybe_tune(self, state) -> None:
-        """First-dispatch autotuning: measure the candidate grid and cache
-        the winners in the session's TuningCache.
-
-        A probe discovery finds every tunable op (kernel available, not
-        ``naive``, not already measured for its ``tune_key``).  Candidate
-        configurations are index-aligned across ops — variant ``j`` pins
-        each op to its ``min(j, len-1)``-th candidate — and each variant is
-        a throwaway ``Program`` with ``overrides`` set, dispatched once to
-        warm/compile and once timed end-to-end.  The fastest variant's
-        per-op configs are stored keyed by ``tune_key``, so the real build
-        that follows (and any later program/map_reduce/serve dispatch with
-        the same op) picks them up from the cache.  Streamed (chunked-
-        source) programs are skipped: their operands arrive per dispatch.
-        """
-        from repro.core import cost as cost_mod
-
-        session = self._session
-        tuning = session.tuning
-        probe = self._discover(state)
-        if any(
-            _mr._source_kind(s.source) == "chunked"
-            for s in probe.live_sources()
-        ):
-            return
-        cand_lists: list[tuple[str, list]] = []
-        seen: set[str] = set()
-        for n in probe.mapreduce_nodes():
-            if n.dead or n.cse_of is not None:
-                continue
-            if n.tuned is not None or n.tune_key in seen:
-                continue
-            if tuning.peek(n.tune_key) is not None:
-                continue
-            info = probe.tune_info.get(n.idx)
-            if info is None:
-                continue
-            tkind, k, v, red_name, dtype_s, key_range, has_kernel = info
-            if not has_kernel or n.engine_requested == "naive":
-                continue
-            dtype = jnp.dtype(dtype_s)
-            if tkind == "hash":
-                cands = cost_mod.hash_tuning_candidates(
-                    v, red_name, dtype, key_range=key_range
-                )
-            else:
-                cands = cost_mod.dense_tuning_candidates(k, v, red_name, dtype)
-            if len(cands) < 2:
-                continue
-            seen.add(n.tune_key)
-            cand_lists.append((n.tune_key, cands))
-        if not cand_lists:
-            return
-        n_variants = max(len(c) for _, c in cand_lists)
-        best_wall, best_set = None, None
-        measured = 0
-        for j in range(n_variants):
-            ov = {
-                tk: cands[min(j, len(cands) - 1)] for tk, cands in cand_lists
-            }
-            variant = Program(
-                session, self._step_fn, mesh=self._mesh, passes=self._passes,
-                overrides=ov, hierarchical=self._hierarchical,
-            )
-            try:
-                faults.fault_point("tuning.measure")
-                out = variant(state, 1)
-                jax.block_until_ready(jax.tree_util.tree_leaves(out))
-                t0 = time.perf_counter()
-                out = variant(state, 1)
-                jax.block_until_ready(jax.tree_util.tree_leaves(out))
-                wall = time.perf_counter() - t0
-            except faults.InjectedFault as e:
-                # A faulted candidate is simply not measured — tuning is an
-                # optimisation, so the fault is absorbed, never retried.  A
-                # real exception propagates: it is a fault, not a slow config.
-                faults.record("absorbed", e)
-                continue
-            measured += 1
-            if best_wall is None or wall < best_wall:
-                best_wall, best_set = wall, ov
-        tuning.record_measurements(measured)
-        session.stats.tune_measurements += measured
-        if best_set is None:
-            return
-        for tk, cfg in best_set.items():
-            tuning.put(
-                tk,
-                dataclasses.replace(cfg, source="measured", wall_s=best_wall),
-            )
-
     def build(self, state) -> Plan:
         """Discover, optimize and lower the plan for ``state``'s signature
         WITHOUT dispatching (compilation itself stays lazy under jit).
@@ -1207,8 +1076,6 @@ class Program:
         if key in self._cache:
             self.plan = self._plans[key]
             return self._cache[key]
-        if self._tune and self._overrides is None:
-            self._maybe_tune(state)
         plan = self._discover(state)
         self._plans[key] = plan
         self.plan = plan
@@ -1387,12 +1254,12 @@ class Program:
         """Degrade every live Pallas node of this program to eager.
 
         Called by the session supervisor on a kernel fault: the faulted
-        nodes' ``tune_key``s go into the session's degraded set (so every
+        nodes' ``resolved_hash``s go into the session's degraded set (so every
         later build — this program's, a per-op call's, or another
         program's — resolves them straight to eager) and the compiled
         executables are dropped so the next dispatch rebuilds.  Carry state
-        (residuals, hash tables) survives the rebuild; the tuning cache is
-        never touched.  Returns how many nodes were degraded.
+        (residuals, hash tables) survives the rebuild.  Returns how many
+        nodes were degraded.
         """
         degraded = getattr(self._session, "_degraded", None)
         if degraded is None:
@@ -1406,7 +1273,7 @@ class Program:
                     and not node.dead
                     and node.cse_of is None
                 ):
-                    degraded.add(node.tune_key)
+                    degraded.add(node.resolved_hash)
                     hit = True
                     n += 1
             if hit:

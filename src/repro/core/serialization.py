@@ -18,8 +18,8 @@ This module is therefore two things:
 
 2. **A faithful host-side reference** of the paper's byte-level format
    (varint, tag-free, fixed field order) next to a Protobuf-style tagged
-   encoding, used by ``benchmarks/bench_serialization.py`` to reproduce the
-   paper's message-size claims analytically.
+   encoding, with which ``tests/test_serialization.py`` checks the paper's
+   message-size claims analytically.
 """
 from __future__ import annotations
 
@@ -106,7 +106,7 @@ def quantize_with_feedback(
 
 
 # ---------------------------------------------------------------------------
-# 2) Host-side reference of the paper's byte format (for benchmarks/analysis)
+# 2) Host-side reference of the paper's byte format (for analysis and tests)
 # ---------------------------------------------------------------------------
 
 

@@ -25,7 +25,6 @@ create/receive one explicitly.
 from __future__ import annotations
 
 import dataclasses
-import os
 import threading
 import time
 from typing import Any, Callable
@@ -76,7 +75,6 @@ class SessionStats:
     host_syncs: int = 0  # blocking host materialisations (host_value/cond)
     program_compiles: int = 0  # fused-program executables built
     program_dispatches: int = 0  # fused-program blocks launched
-    tune_measurements: int = 0  # candidate configs timed by the autotuner
     retries: int = 0  # transient-fault dispatches re-attempted
     degraded_nodes: int = 0  # pallas nodes demoted to eager after a kernel fault
     escalations: int = 0  # hash targets regrown after overflow
@@ -108,7 +106,7 @@ class BlazeSession:
     """
 
     def __init__(
-        self, mesh: Mesh | None = None, *, tuning_path: str | None = None,
+        self, mesh: Mesh | None = None, *,
         retry: faults.RetryPolicy | None = _DEFAULT_RETRY,
         escalate_overflow: bool = False, max_escalations: int = 3,
     ):
@@ -127,21 +125,12 @@ class BlazeSession:
         self.retry = retry
         self.escalate_overflow = escalate_overflow
         self.max_escalations = max_escalations
-        # tune_keys of nodes demoted to eager after a pallas kernel fault.
+        # resolved_hashes of nodes demoted to eager after a pallas kernel fault.
         # Consulted by every node build (per-op, program discovery, serve),
         # so a node degraded once stays degraded for the session — and its
         # eager executable caches under a *different* signature, leaving the
         # faulted pallas entry's cache slots untouched (no poisoning).
         self._degraded: set = set()
-        # Measured autotuning winners, keyed by node plan-hash.  Populated by
-        # tune=True dispatches; consulted by EVERY node build (per-op,
-        # program discovery, serve), so a winner measured once is reused by
-        # all later dispatches of the same plan.  ``tuning_path`` preloads a
-        # cache persisted beside checkpoints (``save_tuning``).
-        self.tuning = cost_mod.TuningCache()
-        self._tuning_path = tuning_path
-        if tuning_path and os.path.exists(tuning_path):
-            self.tuning.load(tuning_path)
         # Session state (exec cache, stats, program carries) is not safe to
         # mutate from concurrent threads.  Multi-threaded front-ends — the
         # serving layer's dispatcher, notably — serialize all session work
@@ -168,10 +157,8 @@ class BlazeSession:
         engine: str = "eager",
         wire: str = "none",
         env: Any = None,
-        shuffle_slack: float = 2.0,
         key_range: int | None = None,
         return_stats: bool = False,
-        tune: bool = False,
         hierarchical: bool = True,
     ):
         """Run one MapReduce op, reusing this session's compiled executables.
@@ -193,19 +180,12 @@ class BlazeSession:
         ``MapReduceStats.plan_hash`` carries the node's stable digest — equal
         to the hash the same op gets inside a fused program.
 
-        ``tune=True`` enables first-dispatch autotuning: if this node's plan
-        hash has no measured winner yet, a small candidate grid (engine ∈
-        {eager, pallas} × kernel block/capacity configs from the shared
-        ``cost`` grids) is timed once, and the winner is cached in
-        ``session.tuning`` — every later dispatch of the same plan (tuned or
-        not, per-op or inside a program) reuses it.
-
         On a multi-node ``("node", "data")`` mesh the
         ``hierarchical-collectives`` pass rewrites eligible dense reductions
         to the topology-aware two-hop plan (intra-node full precision,
         inter-node wire-compressed); ``hierarchical=False`` keeps the flat
-        collective — the A/B baseline ``benchmarks/bench10_scaling.py``
-        measures against.  A no-op on 1-D meshes either way.
+        collective, the reference the hierarchical plan is tested against
+        (``tests/test_multihost.py``).  A no-op on 1-D meshes either way.
         """
         red = get_reducer(reducer)
         mesh = mesh or self.mesh
@@ -215,35 +195,22 @@ class BlazeSession:
             idx=0, kind=kind, src=plan_mod.source_desc(kind, source),
             source_key=None, mapper=mapper, red=red, target=target,
             engine=engine, wire=wire, key_range=key_range, env=env,
-            tuning=self.tuning, degraded=self._degraded,
+            degraded=self._degraded,
             n_nodes=C.n_nodes(mesh), hierarchical=hierarchical,
         )
-        if (
-            tune
-            and node.tuned is None
-            and kind != "chunked"
-            and self._tunable(node, red, target)
-        ):
-            self._tune_map_reduce(
-                kind, source, mapper, red, target, mesh, n_shards, wire,
-                env, shuffle_slack, key_range, node,
-            )
-            cfg = self.tuning.peek(node.tune_key)
-            if cfg is not None:
-                plan_mod.apply_tuned(node, red, cfg)
         engine = node.engine
 
         if isinstance(source, C.ChunkedDistVector):
             return self._map_reduce_chunked(
                 source, mapper, red, target, mesh, n_shards, engine, wire,
-                env, shuffle_slack, key_range, node, return_stats,
+                env, key_range, node, return_stats,
             )
         if isinstance(target, C.DistHashMap):
             def dispatch_hash(tgt):
                 return _mr._map_reduce_hash(
                     kind, source, mapper, red, tgt, mesh, n_shards,
-                    node.engine, shuffle_slack, env, key_range=key_range,
-                    cache=self._exec_cache, node=node, tuned=node.tuned,
+                    node.engine, env, key_range=key_range,
+                    cache=self._exec_cache, node=node,
                 )
 
             out, stats = self._dispatch_supervised(
@@ -257,8 +224,7 @@ class BlazeSession:
                 lambda: _mr._map_reduce_dense(
                     kind, source, mapper, red, jnp.asarray(target), mesh,
                     n_shards, node.engine, wire, env, return_stats,
-                    cache=self._exec_cache, node=node, tuned=node.tuned,
-                    hier=node.hier,
+                    cache=self._exec_cache, node=node, hier=node.hier,
                 ),
                 node,
             )
@@ -270,7 +236,7 @@ class BlazeSession:
 
     def _map_reduce_chunked(
         self, source, mapper, red, target, mesh, n_shards, engine, wire,
-        env, shuffle_slack, key_range, node, return_stats, prefetch=True,
+        env, key_range, node, return_stats, prefetch=True,
     ):
         """Out-of-core standalone map_reduce: one dispatch per block.
 
@@ -305,8 +271,8 @@ class BlazeSession:
                 out, st = self._dispatch_supervised(
                     lambda bv=bv, out=out: _mr._map_reduce_hash(
                         "chunked", bv, mapper, red, out, mesh, n_shards,
-                        node.engine, shuffle_slack, env, key_range=key_range,
-                        cache=self._exec_cache, node=node, tuned=node.tuned,
+                        node.engine, env, key_range=key_range,
+                        cache=self._exec_cache, node=node,
                     ),
                     node,
                 )
@@ -315,8 +281,7 @@ class BlazeSession:
                     lambda bv=bv, out=out: _mr._map_reduce_dense(
                         "chunked", bv, mapper, red, out, mesh, n_shards,
                         node.engine, wire, env, return_stats,
-                        cache=self._exec_cache, node=node, tuned=node.tuned,
-                        hier=node.hier,
+                        cache=self._exec_cache, node=node, hier=node.hier,
                     ),
                     node,
                 )
@@ -369,7 +334,7 @@ class BlazeSession:
 
         Every injected fault is recorded in ``faults.registry`` under exactly
         one disposition, so the chaos suite's conservation law
-        (injected == retried + degraded + escalated + fatal + absorbed)
+        (injected == retried + degraded + escalated + fatal)
         holds across any schedule.
         """
         policy = self.retry
@@ -408,14 +373,14 @@ class BlazeSession:
     def _degrade_op_node(self, node, e) -> None:
         """Demote a per-op node to eager after an injected kernel fault.
 
-        The tune_key lands in ``self._degraded`` so every later build of the
-        same logical node (per-op, program, serve) is born degraded; the
+        Its ``resolved_hash`` lands in ``self._degraded`` so every later
+        build of the same logical node (per-op, program, serve) is born degraded; the
         faulted pallas executable's cache entry is dropped, and the eager
         rebuild caches under the node's *new* signature (engine is part of
         ``stable_desc``), so the pallas entry can never be served again —
         and nothing else in the cache is touched.
         """
-        self._degraded.add(node.tune_key)
+        self._degraded.add(node.resolved_hash)
         if node.cache_sig is not None:
             self._exec_cache.pop(node.cache_sig, None)
         plan_mod.degrade_node(node)
@@ -550,128 +515,10 @@ class BlazeSession:
         )
         return dataclasses.replace(grown, table=table)
 
-    # -- measured autotuning (tune=True) -------------------------------------
-
-    @staticmethod
-    def _tunable(node, red: Reducer, target) -> bool:
-        """Nodes the measured autotuner can act on: a builtin reducer whose
-        kernel exists for the target kind, and no ``naive`` request (naive is
-        a benchmarking baseline, not a candidate)."""
-        kernel = (
-            red.pallas_hash
-            if isinstance(target, C.DistHashMap)
-            else red.pallas_segment
-        )
-        return kernel is not None and node.engine_requested != "naive"
-
-    def _candidates_for(self, red: Reducer, target, key_range):
-        """The measurement grid for one node, off the shared cost grids."""
-        if isinstance(target, C.DistHashMap):
-            val_shape = target.table.vals.shape[2:]
-            v = int(np.prod(val_shape)) if val_shape else 1
-            return cost_mod.hash_tuning_candidates(
-                v, red.name, target.table.vals.dtype, key_range=key_range
-            )
-        t = jnp.asarray(target)
-        k = t.shape[0] if t.ndim else 0
-        v = int(np.prod(t.shape[1:])) if t.ndim > 1 else 1
-        return cost_mod.dense_tuning_candidates(k, v, red.name, t.dtype)
-
-    def _tune_map_reduce(
-        self, kind, source, mapper, red, target, mesh, n_shards, wire, env,
-        shuffle_slack, key_range, node,
-    ):
-        """Time the candidate grid for ``node`` and cache the winner.
-
-        Each candidate is dispatched twice — once to compile + warm, once
-        timed to completion (``block_until_ready``) — through the normal
-        engine entry points, so candidate executables land in the session's
-        executable cache and the winning config's executable is already warm
-        for the real dispatch that follows.  ``map_reduce`` is functional
-        (the target is merged into a *new* container), so the measurement
-        outputs are simply discarded.
-        """
-        hash_target = isinstance(target, C.DistHashMap)
-        candidates = self._candidates_for(red, target, key_range)
-        best_cfg, best_wall = None, float("inf")
-        measured = 0
-        for cfg in candidates:
-            tuned = cfg if cfg.engine == "pallas" else None
-
-            def run():
-                if hash_target:
-                    return _mr._map_reduce_hash(
-                        kind, source, mapper, red, target, mesh, n_shards,
-                        cfg.engine, shuffle_slack, env, key_range=key_range,
-                        cache=self._exec_cache, tuned=tuned,
-                    )
-                return _mr._map_reduce_dense(
-                    kind, source, mapper, red, jnp.asarray(target), mesh,
-                    n_shards, cfg.engine, wire, env, False,
-                    cache=self._exec_cache, tuned=tuned, hier=node.hier,
-                )
-
-            try:
-                faults.fault_point("tuning.measure")
-                out, st = run()  # compile + warm
-                leaves = (
-                    (out.table.keys, out.table.vals, out.table.overflow)
-                    if hash_target
-                    else out
-                )
-                jax.block_until_ready(leaves)
-                t0 = time.perf_counter()
-                out, st2 = run()
-                leaves = (
-                    (out.table.keys, out.table.vals, out.table.overflow)
-                    if hash_target
-                    else out
-                )
-                jax.block_until_ready(leaves)
-                wall = time.perf_counter() - t0
-            except faults.InjectedFault as e:
-                # A faulted measurement just loses the race — the candidate
-                # is skipped, nothing retries, and the ledger records the
-                # injection as absorbed.  A real exception propagates: a
-                # candidate that cannot run is a fault, not a slow config.
-                faults.record("absorbed", e)
-                continue
-            measured += 1
-            self.stats.compiles += st.compiles + st2.compiles
-            self.stats.cache_hits += st.cache_hits + st2.cache_hits
-            if wall < best_wall:
-                best_cfg, best_wall = cfg, wall
-        self.tuning.record_measurements(measured)
-        self.stats.tune_measurements += measured
-        if best_cfg is not None:
-            self.tuning.put(
-                node.tune_key,
-                dataclasses.replace(
-                    best_cfg, source="measured", wall_s=best_wall
-                ),
-            )
-
-    def save_tuning(self, path: str | None = None) -> str:
-        """Persist the tuning cache (JSON, atomic) — call it beside your
-        checkpoint writes.  Defaults to the session's ``tuning_path``."""
-        path = path or self._tuning_path
-        if not path:
-            raise ValueError("no path given and session has no tuning_path")
-        self.tuning.save(path)
-        return path
-
-    def load_tuning(self, path: str | None = None) -> int:
-        """Merge a persisted tuning cache into this session; returns the
-        number of entries loaded."""
-        path = path or self._tuning_path
-        if not path:
-            raise ValueError("no path given and session has no tuning_path")
-        return self.tuning.load(path)
-
     # -- fused iteration programs (see repro.core.program) -------------------
 
     def program(self, step_fn: Callable, *, mesh=None, passes=None,
-                tune: bool = False, hierarchical: bool = True):
+                hierarchical: bool = True):
         """Lower ``step_fn(ctx, state) -> state`` — a whole iteration of
         MapReduce ops plus elementwise glue — into ONE optimized executable.
 
@@ -683,21 +530,14 @@ class BlazeSession:
         engine resolution, collective batching, CSE, dead-source pruning;
         ``passes=()`` disables the optional three for A/B comparisons, and
         ``hierarchical=False`` keeps collectives flat on a multi-node mesh
-        (the scaling bench's baseline).  Run the result with
+        (the reference the hierarchical plan is tested against).  Run the result with
         ``program(state, n_iters)`` or ``run_loop``; render the plan with
         ``session.explain(program)``.
-
-        ``tune=True``: on the program's first build, any tunable node without
-        a measured winner triggers one measurement sweep — throwaway program
-        variants with candidate engine/kernel configs are each dispatched for
-        one timed iteration, and the per-node winners land in
-        ``session.tuning``, shared with every later program, per-op call and
-        BlazeServe query over the same plan.
         """
         from repro.core.program import Program
 
         return Program(
-            self, step_fn, mesh=mesh or self.mesh, passes=passes, tune=tune,
+            self, step_fn, mesh=mesh or self.mesh, passes=passes,
             hierarchical=hierarchical,
         )
 

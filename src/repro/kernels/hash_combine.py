@@ -34,7 +34,7 @@ dropped.  An existing table can be passed as ``init`` — the kernel then
 ``hashmap_insert``'s probe sequence, so eager- and kernel-built tables place
 keys identically.
 
-``choose_table_cap`` autotunes (capacity, block size, probe depth) under a
+``choose_table_cap`` picks (capacity, block size, probe depth) under a
 VMEM budget; ``interpret=None`` resolves via ``pallas_interpret_default`` —
 interpret exactly when the backend is not a TPU — so CPU CI runs the exact
 kernel program TPUs run.
@@ -66,8 +66,8 @@ REDUCERS = ("sum", "prod", "min", "max")
 # tests/test_hash_kernel.py.
 EMPTY_KEY = np.iinfo(np.int32).min
 
-# The (capacity, block, probe-depth) tuner arithmetic is shared with the
-# dense tuner and the measured autotuner in repro.core.cost; the delegates
+# The (capacity, block, probe-depth) sizing arithmetic is shared with the
+# dense kernel's in repro.core.cost; the delegates
 # import lazily at call time (a module-level import would re-enter
 # repro.core.__init__ mid-import — same constraint as segment_reduce).
 

@@ -27,7 +27,7 @@ Grid iterates over pair-blocks (sequential on TPU); the output BlockSpec maps
 every step to the same ``[K, V]`` tile, so the accumulator never leaves VMEM
 between steps.  Negative ids and ids ``>= K`` never match the iota and are
 dropped.  Blocks are whole multiples of the 128 lanes; ``choose_block_n``
-autotunes the block size against a VMEM budget per strategy.
+picks the block size against a VMEM budget per strategy.
 ``interpret=None`` resolves via ``pallas_interpret_default()`` — interpret
 mode exactly when the backend is not a TPU — so CPU runs exercise the same
 kernel program the chip compiles.
@@ -47,7 +47,7 @@ REDUCERS = ("sum", "prod", "min", "max")
 LANES = 128
 
 # The VMEM-budget/candidate-scoring arithmetic lives in repro.core.cost
-# (shared with the hash-combine tuner and the measured autotuner).  The
+# (shared with the hash-combine kernel's sizing).  The
 # delegates below import it lazily at call time: a module-level import would
 # re-enter repro.core.__init__ while this module is itself being imported by
 # the containers → reducers → kernels chain.

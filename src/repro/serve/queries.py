@@ -82,12 +82,10 @@ class ServeResources:
     from a dataset must be built once and reused — program source identity
     is keyed on the backing buffers)."""
 
-    def __init__(self, session, mesh, datasets: dict[str, DatasetEntry],
-                 tune: bool = False):
+    def __init__(self, session, mesh, datasets: dict[str, DatasetEntry]):
         self.session = session
         self.mesh = mesh
         self.datasets = datasets
-        self.tune = tune  # first-prepare autotuning for every built program
         self._derived: dict[tuple, Any] = {}
 
     def dataset(self, name) -> DatasetEntry:
@@ -181,7 +179,7 @@ class PiQuery(QuerySpec):
     def prepare(self, res, params):
         n = _int(params, "n_samples", 4096, 1)
         step, state0 = _pi_step(n, _engine(params))
-        prog = res.session.program(step, mesh=res.mesh, tune=res.tune)
+        prog = res.session.program(step, mesh=res.mesh)
         plan = prog.build(state0)
 
         def run(p):
@@ -227,7 +225,7 @@ class PageRankQuery(QuerySpec):
         step, state0 = _pagerank_step(
             edges_v, deg, n_pages, damping, _engine(params), _wire(params)
         )
-        prog = res.session.program(step, mesh=res.mesh, tune=res.tune)
+        prog = res.session.program(step, mesh=res.mesh)
         init = state0(jnp.full((n_pages,), 1.0 / n_pages, jnp.float32))
         plan = prog.build(init)
 
@@ -272,7 +270,7 @@ class WordCountQuery(QuerySpec):
         step, state0 = _wordcount_step(
             lines_v, hm, vocab_bound, _engine(params)
         )
-        prog = res.session.program(step, mesh=res.mesh, tune=res.tune)
+        prog = res.session.program(step, mesh=res.mesh)
         plan = prog.build(state0)
 
         def run(p):
@@ -312,7 +310,7 @@ class KMeansQuery(QuerySpec):
         step, state0 = _kmeans_step(
             pts_v, k, dim, _engine(params), _wire(params)
         )
-        prog = res.session.program(step, mesh=res.mesh, tune=res.tune)
+        prog = res.session.program(step, mesh=res.mesh)
 
         def init_for(p):
             rng = np.random.RandomState(_int(p, "seed", 0, 0))
@@ -358,7 +356,7 @@ class GMMQuery(QuerySpec):
 
         rows_v = res.derived(("gmm", entry.name, k), build)
         step, state0 = _gmm_step(rows_v, k, d, n, _engine(params))
-        prog = res.session.program(step, mesh=res.mesh, tune=res.tune)
+        prog = res.session.program(step, mesh=res.mesh)
 
         def init_for(p):
             rng = np.random.RandomState(_int(p, "seed", 0, 0))
@@ -410,7 +408,7 @@ class KNNQuery(QuerySpec):
         kk = min(k, per)
         m = min(k, kk * n_shards)
         step = _knn_step(pts_v, k, "auto")
-        prog = res.session.program(step, mesh=res.mesh, tune=res.tune)
+        prog = res.session.program(step, mesh=res.mesh)
 
         def state_for(p):
             q = p.get("query")

@@ -94,7 +94,6 @@ class BlazeServer:
         max_batch: int = 8,
         request_timeout: float = 120.0,
         queries: dict[str, QuerySpec] | None = None,
-        tune: bool = False,
     ):
         self.session = session if session is not None else BlazeSession(mesh)
         self.mesh = mesh if mesh is not None else self.session.mesh
@@ -105,13 +104,7 @@ class BlazeServer:
         self._queue = AdmissionQueue(max_queue, per_tenant_inflight)
         self._specs = builtin_specs() if queries is None else dict(queries)
         self._datasets: dict[str, DatasetEntry] = {}
-        # ``tune=True``: every query's first prepare measures its candidate
-        # engine/block configs (program autotuning) and caches winners in
-        # the resident session's TuningCache — later prepares of plans
-        # containing the same ops reuse them without re-measuring.
-        self._resources = ServeResources(
-            self.session, self.mesh, self._datasets, tune=tune
-        )
+        self._resources = ServeResources(self.session, self.mesh, self._datasets)
         self._programs: dict[tuple, PreparedQuery] = {}  # the plan cache
         self._running = False
         self._paused = threading.Event()
@@ -401,7 +394,6 @@ class BlazeServer:
         snap["datasets"] = sorted(self._datasets)
         snap["mesh_shards"] = C.shard_count(self.mesh)
         snap["mesh_nodes"] = C.n_nodes(self.mesh)
-        snap["tuning"] = self._tuning_snapshot()
         snap["recovery"] = self._recovery_snapshot()
         return snap
 
@@ -421,51 +413,6 @@ class BlazeServer:
             "faults_injected": ledger["injected_total"],
             "dispositions": ledger["dispositions"],
             "balanced": ledger["balanced"],
-        }
-
-    def _tuning_snapshot(self) -> dict:
-        """Per-resident-plan engine/config provenance.
-
-        A plan is "tuned" when at least one of its ops runs a measured (or
-        disk-loaded) winner; otherwise it runs entirely on the calibrated
-        cost model ("fallback").  ``tuned_plans + fallback_plans`` always
-        equals ``resident_programs`` — the conservation the serve tests pin.
-        """
-        tuned_plans = 0
-        per_plan = {}
-        for prep in self._programs.values():
-            plan = prep.program.plan
-            ops, measured = [], False
-            for n in (plan.mapreduce_nodes() if plan is not None else []):
-                if n.dead or n.cse_of is not None:
-                    continue
-                cfg = n.tuned
-                if cfg is not None:
-                    measured = measured or cfg.source in ("measured", "loaded")
-                    ops.append({
-                        "op": n.idx, "engine": n.engine,
-                        "config": cfg.describe(), "source": cfg.source,
-                        "wall_ms": (
-                            None if cfg.wall_s is None
-                            else round(cfg.wall_s * 1e3, 3)
-                        ),
-                    })
-                else:
-                    ops.append({
-                        "op": n.idx, "engine": n.engine, "config": None,
-                        "source": "model",
-                        "cost_estimate": n.cost_estimate,
-                    })
-            if measured:
-                tuned_plans += 1
-            per_plan[prep.plan_hash] = {
-                "query": prep.plan_key[0], "tuned": measured, "ops": ops,
-            }
-        return {
-            "tuned_plans": tuned_plans,
-            "fallback_plans": len(self._programs) - tuned_plans,
-            "cache": self.session.tuning.snapshot(),
-            "plans": per_plan,
         }
 
 

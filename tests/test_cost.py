@@ -1,6 +1,6 @@
 """Cost-model unit tests: the shared candidate grids are bit-compatible with
-the pre-PR-8 greedy kernel tuners, the calibrated fallback model reproduces
-the PR 2 static engine rule exactly, and TunedConfig/TuningCache round-trip.
+the kernels' greedy sizing picks, and the calibrated fallback model
+reproduces the PR 2 static engine rule exactly.
 """
 import jax.numpy as jnp
 import pytest
@@ -16,7 +16,7 @@ from repro.kernels import hash_combine as HK
 from repro.kernels import segment_reduce as SK
 
 
-# -- candidate grids == the kernels' greedy tuners ---------------------------
+# -- candidate grids == the kernels' greedy sizing picks ---------------------
 
 
 @pytest.mark.parametrize("reducer", ["sum", "min", "max", "prod"])
@@ -106,61 +106,3 @@ def test_node_cost_orders_engines():
         assert cost.node_cost("naive", k) > cost.node_cost("pallas", k)
     assert cost.node_cost("pallas", 100) < cost.node_cost("eager", 100)
     assert cost.node_cost("pallas", 10_000) > cost.node_cost("eager", 10_000)
-
-
-# -- measurement grids -------------------------------------------------------
-
-
-def test_dense_tuning_candidates_shape():
-    cands = cost.dense_tuning_candidates(64, 8, "sum", jnp.float32)
-    assert cands[0] == cost.TunedConfig(engine="eager")
-    assert all(c.engine == "pallas" and c.block_n for c in cands[1:])
-    assert len({c.block_n for c in cands[1:]}) == len(cands) - 1
-    default = cost.segment_block_candidates(1 << 30, 64, 8)[-1][0]
-    assert cands[1].block_n == default
-
-
-def test_hash_tuning_candidates_key_range_gates_cap_pinning():
-    # without key_range capacity must follow runtime n: engine-only tuning
-    cands = cost.hash_tuning_candidates(1, "sum", jnp.int32, key_range=None)
-    assert [c.engine for c in cands] == ["eager", "pallas"]
-    assert cands[1].table_cap is None
-    # with key_range, full (cap, bn, probes) triples are pinned, cap >= 2x
-    cands = cost.hash_tuning_candidates(1, "sum", jnp.int32, key_range=50)
-    assert cands[0].engine == "eager"
-    for c in cands[1:]:
-        assert c.table_cap >= 2 * 50 and c.block_n and c.probe_depth
-
-
-# -- TunedConfig / TuningCache ----------------------------------------------
-
-
-def test_tuned_config_identity_excludes_outcomes():
-    a = cost.TunedConfig(engine="pallas", block_n=64)
-    b = cost.TunedConfig(
-        engine="pallas", block_n=64, source="measured", wall_s=0.5
-    )
-    assert a == b and hash(a) == hash(b)
-    assert a != cost.TunedConfig(engine="pallas", block_n=32)
-    rt = cost.TunedConfig.from_dict(b.to_dict())
-    assert rt == b and rt.source == "measured" and rt.wall_s == 0.5
-
-
-def test_tuning_cache_counters_and_roundtrip(tmp_path):
-    c = cost.TuningCache()
-    assert c.get("x") is None and c.misses == 1
-    cfg = cost.TunedConfig(
-        engine="pallas", block_n=64, source="measured", wall_s=0.01
-    )
-    c.put("x", cfg)
-    assert c.get("x") == cfg and c.hits == 1
-    assert c.peek("y") is None and c.misses == 1  # peek never counts
-    c.record_measurements(3)
-    snap = c.snapshot()
-    assert snap["entries"] == 1 and snap["measurements"] == 3
-    p = tmp_path / "tuning.json"
-    c.save(str(p))
-    c2 = cost.TuningCache()
-    assert c2.load(str(p)) == 1
-    got = c2.peek("x")
-    assert got == cfg and got.source == "measured" and got.wall_s == 0.01

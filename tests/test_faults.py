@@ -9,7 +9,7 @@ schedules, and held to two laws:
   executable runs or any carry is written, so a retried dispatch replays
   exactly;
 * **conservation** — every injected fault is disposed exactly once:
-  ``injected_total == retried + degraded + escalated + fatal + absorbed``
+  ``injected_total == retried + degraded + escalated + fatal``
   (``faults.snapshot()["balanced"]``), across threads (prefetch worker,
   serve dispatcher) and across any seeded schedule.
 
@@ -22,7 +22,6 @@ hit).
 """
 from __future__ import annotations
 
-import json
 import os
 
 import numpy as np
@@ -273,18 +272,6 @@ def test_real_kernel_exception_propagates_from_program():
     assert prog.stats.dispatches == 0
 
 
-def test_real_tuning_candidate_exception_propagates():
-    sess = _sess()
-    src = sess.distribute(np.arange(256, dtype=np.float32))
-    with pytest.raises(RuntimeError, match="Mosaic"):
-        sess.map_reduce(
-            src, _sq_mapper, _refused_sum(), jnp.zeros((8,), jnp.float32),
-            tune=True,
-        )
-    assert sess.stats.degraded_nodes == 0
-    _assert_balanced(absorbed=0)
-
-
 def _pallas_step(src, reducer="sum"):
     def step(ctx, state):
         def mapper(i, x, emit, env):
@@ -527,7 +514,7 @@ def test_checkpoint_write_fault_is_retried(tmp_path):
     assert info.resumed_from == 4 and info.iterations == 0
 
 
-# -- prefetch + tuning supervisors --------------------------------------------
+# -- prefetch supervisor -----------------------------------------------------
 
 
 def test_prefetch_read_fault_retried_in_worker():
@@ -544,41 +531,6 @@ def test_prefetch_read_fault_retried_in_worker():
     np.testing.assert_array_equal(np.asarray(out), ref)
     snap = faults.snapshot()
     assert snap["balanced"] and snap["dispositions"]["retried"] >= 1
-
-
-def test_tuning_measurement_fault_absorbed():
-    sess = _sess()
-    src = sess.distribute(np.arange(256, dtype=np.float32))
-    target = jnp.zeros((8,), jnp.float32)
-    faults.configure("tuning.measure", at=1)
-    out = sess.map_reduce(src, _sq_mapper, "sum", target, tune=True)
-    ref_sess = _sess()
-    ref = ref_sess.map_reduce(
-        ref_sess.distribute(np.arange(256, dtype=np.float32)),
-        _sq_mapper, "sum", target,
-    )
-    # the faulted candidate lost the race; the winner may be pallas, whose
-    # float association differs — allclose, not bit-equal, is the contract
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6)
-    _assert_balanced(absorbed=1)
-
-
-# -- corrupt tuning cache (satellite) -----------------------------------------
-
-
-def test_corrupt_tuning_json_warns_and_starts_empty(tmp_path):
-    path = str(tmp_path / "tuning.json")
-    with open(path, "w") as f:
-        f.write("{definitely not json")
-    with pytest.warns(RuntimeWarning, match="unreadable tuning cache"):
-        sess = BlazeSession(tuning_path=path)
-    assert sess.tuning.snapshot()["entries"] == 0
-    with pytest.warns(RuntimeWarning):
-        assert sess.load_tuning(path) == 0
-    # the session still works and can overwrite the corpse atomically
-    sess.save_tuning(path)
-    with open(path) as f:
-        json.load(f)  # valid JSON again
 
 
 # -- seeded chaos schedules over real drivers ---------------------------------

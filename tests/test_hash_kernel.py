@@ -234,6 +234,53 @@ def test_choose_table_cap_autotuner():
     assert HK.choose_probe_depth(10, 4) <= 4
 
 
+# (reducer, value dtype): integer counts, integer-valued f32 sums (exact in
+# any fold order) and f32 maxima.  Keys span _HGRID_DISTINCT values, the
+# ``distinct_hint`` that fixes the grid's capacity.
+_HGRID_SETTINGS = {
+    "i32-sum": ("sum", np.int32),
+    "f32-sum": ("sum", np.float32),
+    "f32-max": ("max", np.float32),
+}
+_HGRID_N, _HGRID_DISTINCT = 2048, 50
+
+
+def _hash_grid_cases():
+    from repro.core import cost
+
+    return [
+        pytest.param(name, cap, bn, probes, id=f"{name}-cap{cap}-bn{bn}")
+        for name, (red, dt) in _HGRID_SETTINGS.items()
+        for cap, bn, probes, _ws in cost.hash_table_candidates(
+            _HGRID_N, 1, red, dt, distinct_hint=_HGRID_DISTINCT
+        )
+    ]
+
+
+@pytest.mark.parametrize("setting,cap,block_n,probes", _hash_grid_cases())
+def test_kernel_same_contents_at_every_grid_point(setting, cap, block_n, probes):
+    """The kernel's table does not depend on its launch geometry: every
+    ``(cap, block_n, probes)`` of ``cost.hash_table_candidates`` holds the
+    dict oracle's contents, with nothing overflowed."""
+    reducer, dtype = _HGRID_SETTINGS[setting]
+    r = np.random.RandomState(11)
+    keys = r.randint(0, _HGRID_DISTINCT, _HGRID_N).astype(np.int32)
+    if reducer == "sum":
+        vals = r.randint(-20, 21, _HGRID_N).astype(dtype)
+    else:
+        vals = r.randn(_HGRID_N).astype(dtype)
+    tk, tv, ovf = HK.hash_aggregate(
+        jnp.asarray(keys), jnp.asarray(vals[:, None]), cap, reducer=reducer,
+        max_probes=probes, block_n=block_n,
+    )
+    assert int(ovf) == 0
+    want = _dict_oracle(keys, vals, reducer)
+    got = _table_dict(tk, tv)
+    assert got.keys() == want.keys()
+    for k, v in want.items():
+        assert got[k] == v, (k, got[k], v)
+
+
 def test_kernel_lanes_accounting():
     # blocks are whole 128-lane tiles: a request below one tile rounds up
     bn, lanes = HK.hash_aggregate_lanes(100, 256, 1, block_n=64)

@@ -182,6 +182,54 @@ def test_segment_reduce_autotune_and_lanes():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4)
 
 
+# (reducer, value dtype, integer-valued inputs): a float sum on the MXU
+# one-hot matmul, a float max on the VPU select fold, an exact int32 sum.
+# Integer-valued float sums are exact in any order, so every block size
+# must give the same bits.
+_GRID_SETTINGS = {
+    "f32-sum-mxu": ("sum", np.float32, True),
+    "f32-max-vpu": ("max", np.float32, False),
+    "i32-sum": ("sum", np.int32, True),
+}
+_GRID_N, _GRID_K, _GRID_V = 2048, 16, 4
+
+
+def _segment_grid_cases():
+    from repro.core import cost
+
+    return [
+        pytest.param(name, bn, id=f"{name}-bn{bn}")
+        for name, (red, dt, _) in _GRID_SETTINGS.items()
+        for bn, _ws in cost.segment_block_candidates(
+            _GRID_N, _GRID_K, _GRID_V, red, dt
+        )
+    ]
+
+
+@pytest.mark.parametrize("setting,block_n", _segment_grid_cases())
+def test_segment_reduce_same_bits_at_every_grid_block(setting, block_n):
+    """The kernel's result does not depend on its launch geometry: every
+    ``block_n`` of ``cost.segment_block_candidates`` gives the NumPy
+    oracle's bits (ids outside ``[0, K)`` dropped)."""
+    reducer, dtype, integral = _GRID_SETTINGS[setting]
+    r = np.random.RandomState(7)
+    ids = r.randint(-2, _GRID_K + 2, _GRID_N).astype(np.int32)
+    if integral:
+        vals = r.randint(-50, 51, (_GRID_N, _GRID_V)).astype(dtype)
+    else:
+        vals = r.randn(_GRID_N, _GRID_V).astype(dtype)
+    got = segment_reduce(
+        jnp.asarray(ids), jnp.asarray(vals), _GRID_K, reducer=reducer,
+        block_n=block_n,
+    )
+    ufunc = {"sum": np.add, "max": np.maximum}[reducer]
+    ident = {"sum": 0, "max": -np.inf}[reducer]
+    want = np.full((_GRID_K, _GRID_V), ident, got.dtype)
+    keep = (ids >= 0) & (ids < _GRID_K)
+    ufunc.at(want, ids[keep], vals[keep].astype(got.dtype))
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
 def test_segment_reduce_nan_in_dropped_lane_stays_out():
     """A non-finite value on a dropped lane (id<0 / id>=K) must not leak:
     0·NaN = NaN through the one-hot matmul unless the lane is zeroed."""

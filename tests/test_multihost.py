@@ -149,12 +149,13 @@ def test_pass_rewrites_eligible_nodes_only():
 
 
 def test_hier_node_is_a_distinct_plan_identity():
-    """The hier rewrite lands BEFORE tune_key/stable_desc capture: a
-    hierarchical node must not alias the flat node's tuning winners or plan
-    hash (they compile different collectives)."""
+    """The hier rewrite lands BEFORE resolved_hash/stable_desc capture: a
+    hierarchical node must not alias the flat node's degraded-set key or
+    plan hash (they compile different collectives)."""
     flat, hier = _node(1), _node(2)
     assert flat.stable_desc() != hier.stable_desc()
-    assert flat.tune_key != hier.tune_key
+    assert flat.resolved_hash != hier.resolved_hash
+    assert hier.resolved_hash == hier.hash
     assert hier.stable_desc().endswith(" hier")
 
 
